@@ -28,9 +28,9 @@ from .common import result_payload, save_result
 from repro.core.channel import WirelessConfig, make_deployment
 from repro.core.bounds import ObjectiveWeights
 from repro.core import ota_design, digital_design
-
 # Objective-quality gate: jax <= scipy * (1 + PARITY_RTOL) per grid point.
-PARITY_RTOL = 1e-3
+from repro.core.sca_jax import ORACLE_RTOL as PARITY_RTOL
+from repro import compile_cache
 
 
 def _weight_grid(n_devices: int, grid: tuple[int, int]) -> list[ObjectiveWeights]:
@@ -142,6 +142,7 @@ def main() -> None:
                     help="small grid CI guard: asserts the JAX path matches "
                          "or beats the SCA oracle on every point")
     args = ap.parse_args()
+    compile_cache.enable()
     rows, payload = run(quick=args.smoke)
     print("family,n_points,scipy[s],jax_cold[s],jax_warm[s],speedup_cold,"
           "max_rel_gap")

@@ -44,6 +44,7 @@ from .common import (design_digital, design_ota, dump_json, make_sc_setup,
                      result_payload, save_result)
 from repro.core import baselines as B
 from repro.fl.trainer import FLTrainer
+from repro import compile_cache
 
 
 def _time_backend(trainer, agg, backend, *, rounds, trials, eval_every,
@@ -416,6 +417,7 @@ def main() -> None:
                     help="with --digital-long/--scale: exit 1 if peak RSS "
                          "exceeds")
     args = ap.parse_args()
+    compile_cache.enable()
     if args.scale:
         if args.smoke:
             rows, payload = run_scale(

@@ -6,8 +6,7 @@ target is TPU Mosaic, see kernels/*.py docstrings).
 quantize->pack->dequant-aggregate pipeline at N=256 devices, d=10^6
 (full mode adds d=10^7) against the materialize-then-sum baseline, with
 per-kernel achieved bytes/s and FLOP/s vs the ``benchmarks.roofline``
-peaks, the bf16-payload/f32-accumulate kernel rows, and the
-autotuned-vs-fixed tile comparison. Writes the schema-stamped record to
+peaks, and the bf16-payload/f32-accumulate kernel rows. Writes the schema-stamped record to
 the repo-root ``BENCH_kernel_payload.json`` (tracked across PRs, next to
 ``BENCH_engine_scale.json``). ``--rss-budget-mb`` guards the fused
 phase's peak RSS (exit 1 on overrun — the scripts/verify.sh CI gate that
@@ -24,8 +23,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import autotune, ops
+from repro.kernels import ops
 from repro.kernels.payload import unpack_dequant_rows_2d
+from repro import compile_cache
 
 
 def _time(fn, *args, reps=3):
@@ -212,8 +212,7 @@ def _bf16_kernel_rows(d: int) -> list:
                  "max_abs_deviation": err})
 
     red32 = jax.jit(lambda g: ops.row_maxabs_sumsq(g[None, :]))
-    red16 = jax.jit(lambda g: ops.row_maxabs_sumsq(
-        g[None, :], acc_dtype=jnp.float32))
+    red16 = jax.jit(lambda g: ops.row_maxabs_sumsq(g[None, :]))
     t32, t16 = _time_s(red32, g32), _time_s(red16, g16)
     m32, s32 = red32(g32)
     m16, s16 = red16(g16)
@@ -224,29 +223,6 @@ def _bf16_kernel_rows(d: int) -> list:
     return rows
 
 
-def _autotune_rows(d: int) -> dict:
-    """Chosen tile + the measured per-candidate times it beat, per kernel
-    family (the fixed-512 column is the pre-autotuner behavior)."""
-    rows = -(-d // 128)
-    out = {}
-    for kind in ("pack", "unpack", "quantize"):
-        bench = ops._autotune_bench(kind, jnp.float32)
-        chosen = autotune.choose_block_rows(kind, rows, jnp.float32,
-                                            bench=bench)
-        times = {br: autotune._measure(bench, br)
-                 for br in autotune.CANDIDATES if br <= autotune._pow2_fit(rows)}
-        out[kind] = {
-            "chosen_block_rows": chosen,
-            "fixed_512_s": times.get(512),
-            "chosen_s": times.get(chosen),
-            "speedup_vs_fixed": (times[512] / times[chosen]
-                                 if 512 in times and chosen in times
-                                 else None),
-            "candidate_s": {str(k): v for k, v in times.items()},
-        }
-    return out
-
-
 def run_payload(quick: bool = True, *, rss_budget_mb=None):
     """Payload-scale fused-pipeline benchmark -> BENCH_kernel_payload.json.
 
@@ -255,9 +231,8 @@ def run_payload(quick: bool = True, *, rss_budget_mb=None):
     O(d) accumulator) against materialize-then-sum at N=256 devices,
     d=10^6 — the regime where the (N, d) float block is a gigabyte that
     exists only to be summed. Full mode adds a d=10^7 point at N=32.
-    Also records the bf16-payload/f32-accumulate kernel rows and the
-    autotuned-vs-fixed-512 tile table, all schema-stamped to the repo-root
-    ``BENCH_kernel_payload.json``.
+    Also records the bf16-payload/f32-accumulate kernel rows, all
+    schema-stamped to the repo-root ``BENCH_kernel_payload.json``.
     """
     from .common import dump_json, result_payload
 
@@ -265,10 +240,9 @@ def run_payload(quick: bool = True, *, rss_budget_mb=None):
     if not quick:
         cases.append(_payload_case(32, 10_000_000, 8, chunk=4))
     bf16 = _bf16_kernel_rows(1_000_000)
-    tune = _autotune_rows(1_000_000)
     payload = result_payload(
         "kernel_bench_payload", quick=quick, cases=cases,
-        bf16_kernels=bf16, autotune=tune, rss_budget_mb=rss_budget_mb)
+        bf16_kernels=bf16, rss_budget_mb=rss_budget_mb)
     out = Path(__file__).resolve().parents[1] / "BENCH_kernel_payload.json"
     out.write_text(dump_json(payload))
     rows = []
@@ -297,6 +271,7 @@ def main() -> None:
                     help="with --payload: exit 1 if the FUSED phase's peak "
                          "RSS exceeds this (the O(d) aggregation guard)")
     args = ap.parse_args()
+    compile_cache.enable()
     if not args.payload:
         rows, _ = run(quick=True)
         for r in rows:
@@ -319,10 +294,6 @@ def main() -> None:
         print(f"bf16 {r['kernel']} d={r['dim']}: f32 {r['f32_s'] * 1e3:.1f}ms"
               f" vs bf16-payload {r['bf16_payload_s'] * 1e3:.1f}ms "
               f"(half the payload bytes)")
-    for kind, t in payload["autotune"].items():
-        if t["speedup_vs_fixed"]:
-            print(f"autotune {kind}: tile {t['chosen_block_rows']} "
-                  f"({t['speedup_vs_fixed']:.1f}x vs fixed 512)")
     print(f"-> BENCH_kernel_payload.json")
     gate = payload["cases"][0]
     if (args.rss_budget_mb is not None

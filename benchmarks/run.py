@@ -8,7 +8,8 @@ Usage: PYTHONPATH=src python -m benchmarks.run [--full] [--only NAME]
 
 ``--only`` accepts an exact suite name or a name prefix (``--only fig2``
 runs both fig2 suites); unknown names print the registry instead of a
-KeyError.
+KeyError. A suite that raises prints an ``ERROR`` row; the others still
+run, and the command then exits 1.
 """
 from __future__ import annotations
 
@@ -16,6 +17,8 @@ import argparse
 import sys
 import time
 import types
+
+from repro import compile_cache
 
 
 def _registry() -> dict:
@@ -73,6 +76,7 @@ def main() -> None:
     ap.add_argument("--list", action="store_true",
                     help="list registered suites and exit")
     args = ap.parse_args()
+    compile_cache.enable()
     quick = not args.full
 
     modules = _registry()
@@ -91,18 +95,25 @@ def main() -> None:
         modules = selected
 
     print("name,us_per_call,derived")
+    failed = []
     for name, mod in modules.items():
         t0 = time.time()
         try:
             rows, payload = mod.run(quick=quick)
         except Exception as e:
+            # report and go on to the next suite, but the run fails
             print(f"{name},0,ERROR:{type(e).__name__}:{e}", flush=True)
+            failed.append(name)
             continue
         for r in rows:
             print(f"{r[0]},{r[1]:.1f},{r[2]}", flush=True)
         print(f"{name}/TOTAL,{(time.time() - t0) * 1e6:.0f},ok", flush=True)
         if name == "roofline" and payload.get("table"):
             print(mod.format_table(payload), file=sys.stderr)
+    if failed:
+        print(f"{len(failed)} suite(s) failed: {', '.join(failed)}",
+              file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -59,6 +59,7 @@ from repro.core.bounds import (ObjectiveWeights, async_bias_sum,
 from repro.fl.trainer import FLTrainer, solve_w_star
 
 from .common import estimate_kappa_sc, make_sc_setup, save_result
+from repro import compile_cache
 
 
 def _variants(sweep: SweepSpec):
@@ -277,6 +278,7 @@ def main() -> None:
     ap.add_argument("--jobs", type=int, default=1, metavar="K",
                     help="worker-pool size for the sweep cells")
     args = ap.parse_args()
+    compile_cache.enable()
     quick = not args.full or args.smoke
     rows, payload = run(quick=quick, jobs=args.jobs)
     print("name,us_per_call,derived")

@@ -34,6 +34,7 @@ from repro.api import execute
 from repro.api.scenarios import sweep_participation as make_spec
 
 from .common import save_result
+from repro import compile_cache
 
 
 def run(quick: bool = True, n_devices: int = 50, use_cache: bool = True,
@@ -100,6 +101,7 @@ def main() -> None:
     ap.add_argument("--jobs", type=int, default=1, metavar="K",
                     help="worker-pool size for the sweep cells")
     args = ap.parse_args()
+    compile_cache.enable()
     quick = not args.full or args.smoke
     rows, payload = run(quick=quick, jobs=args.jobs)
     print("name,us_per_call,derived")

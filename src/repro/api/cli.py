@@ -23,6 +23,7 @@ from . import scenarios
 from .execute import default_out_dir, execute
 from .plan import plan
 from .spec import spec_from_dict
+from .. import compile_cache
 
 
 def _load_spec(ref: str, *, quick: bool):
@@ -111,6 +112,7 @@ def main(argv=None) -> int:
                    help="exit 1 if any cell was (re)computed")
 
     args = ap.parse_args(argv)
+    compile_cache.enable()
     return {"list": _cmd_list, "describe": _cmd_describe,
             "run": _cmd_run}[args.cmd](args)
 

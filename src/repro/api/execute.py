@@ -50,6 +50,8 @@ import traceback
 from pathlib import Path
 from typing import Callable, Optional
 
+import jax
+
 from ..core import digital_design, ota_design
 from . import materialize as mat
 from . import schemes
@@ -178,14 +180,15 @@ def _design_pack(ctx) -> tuple:
 _WORKER_MEMO = None
 
 
-def _chaos_hook(cell_hash: str) -> None:
+def _chaos_kill() -> None:
     """Test-only fault injection for the supervisor (env-gated, inert
     otherwise; spawn workers inherit the parent environment).
 
     ``REPRO_CHAOS_KILL_DIR=<dir>`` — SIGKILL exactly one worker, once per
     directory (atomic ``O_CREAT|O_EXCL`` marker), simulating an OOM kill.
-    ``REPRO_CHAOS_HANG_HASH=<prefix>`` — cells whose hash matches the
-    prefix hang, exercising the per-cell timeout path.
+    It fires before the worker announces the cell: a kill while the
+    result queue's feeder thread is still writing that announcement would
+    leave the queue's lock held and hang the sweep.
     """
     kill_dir = os.environ.get("REPRO_CHAOS_KILL_DIR")
     if kill_dir:
@@ -197,6 +200,11 @@ def _chaos_hook(cell_hash: str) -> None:
         else:
             os.close(fd)
             os.kill(os.getpid(), signal.SIGKILL)
+
+
+def _chaos_hang(cell_hash: str) -> None:
+    """Test-only: ``REPRO_CHAOS_HANG_HASH=<prefix>`` — cells whose hash
+    matches the prefix hang, exercising the per-cell timeout path."""
     hang = os.environ.get("REPRO_CHAOS_HANG_HASH")
     if hang and cell_hash.startswith(hang):
         time.sleep(3600)
@@ -206,7 +214,7 @@ def _worker_run_cell(job):
     """Pool worker: re-materialize one cell from pure data and run it."""
     (scenario_dict, index, overrides, cell_hash, design_pack, memo_seed,
      cells_dir) = job
-    _chaos_hook(cell_hash)
+    _chaos_hang(cell_hash)
     global _WORKER_MEMO
     if _WORKER_MEMO is None:
         _WORKER_MEMO = mat.new_memo()
@@ -239,6 +247,7 @@ def _pool_worker(wid: int, jobq, resq) -> None:
         if job is None:
             return
         index = job[1]
+        _chaos_kill()
         resq.put(("start", wid, index))
         try:
             _, payload = _worker_run_cell(job)
@@ -462,6 +471,13 @@ def execute(spec_or_plan, *, out_dir: Optional[Path] = None,
           else make_plan(spec_or_plan))
     if jobs < 1:
         raise ValueError(f"jobs must be >= 1, got {jobs}")
+    if jobs > 1 and jax.default_backend() != "cpu":
+        # every spawned worker imports JAX and would need the accelerator,
+        # which belongs to one process (this one, after the design solves)
+        raise RuntimeError(
+            f"execute(jobs={jobs}) spawns worker processes, but the "
+            f"{jax.default_backend()} backend belongs to one process; "
+            "run with jobs=1")
     if retries < 0:
         raise ValueError(f"retries must be >= 0, got {retries}")
     if cell_timeout_s is not None and cell_timeout_s <= 0:

@@ -20,7 +20,9 @@ contracts as the fault and participation layers:
     per-device gradients (a scan-carried (K, N, d) window in the JAX
     engine); draws with ``S >= K`` fall outside the buffer window and are
     discarded. The staleness CDF thresholds (:func:`staleness_cdf`) and
-    rates are precomputed host-side in float64, so the realized
+    rates are precomputed host-side in float64 and rounded to float32
+    (``rngstream.f32_table``) before either backend compares the f32
+    uniforms against them, so the realized
     delivery/staleness pattern is *bit-identical* across the NumPy oracle,
     the JAX engine, and both rng modes — only exact comparisons against
     shared tables, never transcendentals, happen inside the round loop.
@@ -140,7 +142,8 @@ def staleness_cdf(rates: np.ndarray, buffer_rounds: int) -> np.ndarray:
     ``S ~ geometric(r_m)`` (support {0, 1, ...}): ``P(S <= j) =
     1 - (1-r)^{j+1}``. The round loop compares the staleness uniform
     against these *precomputed* thresholds — counting crossed rows gives
-    the staleness integer with exact float64 comparisons only, so the
+    the staleness integer with exact comparisons only (against the
+    f32-rounded table), so the
     realization is bit-identical across NumPy/JAX (no in-loop logs whose
     last ulp could differ between libm and XLA). A uniform at or above
     row K-1 means S >= K: the update fell out of the buffer window.
@@ -296,18 +299,21 @@ def async_round(g, buf, u, rates, cdf, discounts, pay_scale):
     ``g`` (N, d) is the round's fresh per-device gradients (already
     payload-cast and participation-scaled), ``buf`` (K, N, d) the
     staleness buffer (slot s = gradients computed s rounds ago, before
-    this round's shift), ``u`` the round's (2, N) ARRIVAL uniforms widened
-    to float64, and ``rates`` (N,) / ``cdf`` (K, N) / ``discounts`` (K,) /
-    ``pay_scale`` (N,) the resolved tables in the *caller's* backend dtype
-    (the NumPy oracle passes float64 ndarrays, the engine jnp constants).
+    this round's shift), ``u`` the round's (2, N) f32 ARRIVAL uniforms
+    (the oracle widens them to float64, exactly), and ``rates`` (N,) /
+    ``cdf`` (K, N) / ``discounts`` (K,) / ``pay_scale`` (N,) the resolved
+    tables in the *caller's* backend dtype (the NumPy oracle passes
+    float64 ndarrays with rates/CDF rounded through float32, the engine
+    f32 jnp constants).
 
     Returns ``(payload, ok, buf_new)``: the staleness-discounted delivered
     payloads ``delta^S * v * (N/sum(cv)) * g(w_{t-S})``, the (N,) boolean
     delivery mask (False = no completion this round, or the draw fell out
-    of the buffer window), and the shifted buffer. Every operation is an
-    exact comparison / gather / multiply against the shared float64
-    tables, so the realized mask and staleness integers are bit-identical
-    across NumPy/JAX and both rng modes.
+    of the buffer window), and the shifted buffer. The mask and staleness
+    come from exact comparisons against the shared f32-rounded tables, so
+    the realized mask and staleness integers are bit-identical across
+    NumPy/JAX and both rng modes; the payload itself agrees to f32
+    round-off.
     """
     xp = _xp(g)
     buf = xp.concatenate([g[None], buf[:-1]], axis=0)

@@ -104,7 +104,7 @@ def sample_fading_jax(key, t, lambdas):
     import jax
     import jax.numpy as jnp
     z = jax.random.normal(jax.random.fold_in(key, t),
-                          (2,) + jnp.shape(lambdas), dtype=jnp.float64)
+                          (2,) + jnp.shape(lambdas), dtype=jnp.float32)
     scale = jnp.sqrt(jnp.asarray(lambdas) / 2.0)
     return (z[0] + 1j * z[1]) * scale
 

@@ -24,7 +24,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .. import compat
 from ..kernels import ops as kops
 
 
@@ -74,7 +73,7 @@ def wireless_psum(grads, round_info: WirelessRound, client_axes: tuple,
     if mode == "ideal":
         n = 1
         for a in client_axes:
-            n *= compat.axis_size(a)
+            n *= jax.lax.axis_size(a)
         return cast_back(jax.tree.map(
             lambda g, s: reduce_leaf(g, s) / n, grads, skip_psum))
     if mode == "ota":
@@ -97,7 +96,7 @@ def wireless_psum(grads, round_info: WirelessRound, client_axes: tuple,
         # independent dither even though the key operand is replicated
         cidx = jnp.zeros((), jnp.int32)
         for a in client_axes:
-            cidx = cidx * compat.axis_size(a) + jax.lax.axis_index(a)
+            cidx = cidx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
         key = jax.random.fold_in(key, cidx)
         leaves = jax.tree.leaves(grads)
         keys = jax.random.split(key, len(leaves))

@@ -176,7 +176,7 @@ def digital_round_jax(params: DigitalParams, grads, h, u,
 # jit/vmap/scan-able pieces: instantaneous capacity rates, top-K device
 # selection as a 0/1 mask, and FedTOE's greedy bit allocation. The NumPy
 # oracle implementations live in ``core.baselines``; these mirror them
-# op-for-op so trajectories replay to float64 round-off.
+# op-for-op so trajectories replay to the engine's f32 round-off.
 
 def capacity_rate_jnp(habs, e_s: float, n0: float):
     """Instantaneous spectral efficiency log2(1 + E_s|h|^2/N0) [b/s/Hz]."""
@@ -220,7 +220,7 @@ def greedy_bit_alloc_jax(sel, rates, *, dim: int, bandwidth_hz: float,
     import jax.numpy as jnp
 
     n = rates.shape[0]
-    rates = jnp.asarray(rates, jnp.float64)
+    rates = jnp.asarray(rates, jnp.float32)
     safe_rates = jnp.maximum(rates, 1e-9)
     # stable descending-rate order over the scheduled set, mirroring
     # ``sorted(sel, key=lambda m: -rates[m])``
@@ -232,9 +232,9 @@ def greedy_bit_alloc_jax(sel, rates, *, dim: int, bandwidth_hz: float,
         fits = used + t1 <= t_budget_s
         return used + jnp.where(fits, t1, 0.0), fits
 
-    _, fits = jax.lax.scan(fill, jnp.zeros((), jnp.float64), t_one)
-    in_alloc = jnp.zeros(n, jnp.float64).at[sel_sorted].add(
-        fits.astype(jnp.float64))
+    _, fits = jax.lax.scan(fill, jnp.zeros((), jnp.float32), t_one)
+    in_alloc = jnp.zeros(n, jnp.float32).at[sel_sorted].add(
+        fits.astype(jnp.float32))
     bits0 = in_alloc.copy()
     per_bit_s = dim / (bandwidth_hz * safe_rates)
 

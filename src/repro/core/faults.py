@@ -167,9 +167,11 @@ def fault_masks(u, habs, fault: FaultSpec):
     one place); ``straggler`` marks slowed devices (they only miss the
     round when a deadline is set).
     """
-    dropped = u[0] < fault.dropout_prob
-    erased = u[1] < fault.erasure_prob
-    straggler = u[2] < fault.straggler_prob
+    # probabilities rounded to f32 for both backends: the f32 engine and
+    # the f64 oracle then make the identical comparison on the f32 draws
+    dropped = u[0] < np.float32(fault.dropout_prob)
+    erased = u[1] < np.float32(fault.erasure_prob)
+    straggler = u[2] < np.float32(fault.straggler_prob)
     faded = ~outage_mask(habs, 0.0, deep_fade_thresh=fault.deep_fade_thresh)
     missed = dropped | erased | faded
     if fault.deadline_s is not None:

@@ -168,12 +168,12 @@ def opc_ota_fl_round_jax(grads, h, z01, *, dim: int, g_max: float,
     n = habs.shape[0]
     order = jnp.argsort(habs)[::-1]
     habs_desc = habs[order]
-    ks = jnp.arange(1, n + 1, dtype=jnp.float64)
+    ks = jnp.arange(1, n + 1, dtype=jnp.float32)
     gammas = np.sqrt(dim * e_s) * habs_desc / g_max
     scores = (g_max ** 2 * (1.0 - ks / n) ** 2
               + dim * n0 / (ks * gammas) ** 2)
     kidx = jnp.argmin(scores)             # first minimum, as the oracle
-    k = (kidx + 1).astype(jnp.float64)
+    k = (kidx + 1).astype(jnp.float32)
     gamma = gammas[kidx]
     chi = jnp.zeros(n, grads.dtype).at[order].set(
         (jnp.arange(n) <= kidx).astype(grads.dtype))

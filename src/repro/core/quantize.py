@@ -60,6 +60,8 @@ def quantize_np_dither(g: np.ndarray, r_bits: int,
         return np.zeros_like(g)
     s = _levels(r_bits)
     delta = 2.0 * m / s
+    if delta == 0.0:                         # subnormal m: no grid step
+        return np.zeros_like(g)
     x = (g + m) / delta                      # in [0, s]
     lo = np.floor(x)
     frac = x - lo

@@ -15,9 +15,11 @@ pure function of ``(seed, trial, t)`` computed with the threefry
     scan-carried per-trial key) — O(N*d) live memory per round.
 
 Threefry is deterministic across CPU/TPU and jit/eager, so the two
-backends see bit-identical dither. Uniforms are drawn in float32 and
-widened to float64 by both consumers (exact), keeping the streams equal
-regardless of the oracle's x64-less default config.
+backends see bit-identical dither. Uniforms are drawn in float32: the
+engine consumes them as drawn and the oracle widens them to float64
+(exact). Every threshold they are compared against (participation, fault
+and async tables) is rounded to float32 first (:func:`f32_table`), so the
+comparisons — and the realizations — are identical in both backends.
 
 Mini-batch sampling follows the same counter-based design: the batch
 indices consumed by device ``m`` in round ``t`` of trial ``trial`` are a
@@ -104,6 +106,19 @@ PARTICIPATE_TAG = 59
 ARRIVAL_TAG = 61
 
 
+def f32_table(a) -> np.ndarray:
+    """A float64 threshold table rounded to float32, kept as float64.
+
+    The f32 uniforms of the counter-based streams are compared against
+    thresholds (inclusion probabilities, async rates and CDFs): the
+    engine compares in f32 and the oracle in f64, so the oracle compares
+    against these rounded values — the comparisons, and so the
+    realizations, are then identical in both backends.
+    """
+    return np.asarray(np.asarray(a, np.float64).astype(np.float32),
+                      np.float64)
+
+
 #: Bound on the per-stream (seed, trial) -> base-key memos below.
 _KEY_CACHE_MAX = 256
 
@@ -140,18 +155,16 @@ def stream_base_key(seed: int, trial: int, tag: int) -> jax.Array:
 
 
 def noise_block(key: jax.Array, t, d: int) -> jnp.ndarray:
-    """(d,) float64 standard-normal AWGN draws for round ``t`` (fast mode).
+    """(d,) float32 standard-normal AWGN draws for round ``t`` (fast mode).
 
     ``key`` is the trial's ``stream_base_key(seed, trial, NOISE_TAG)``;
     ``t`` may be a traced scalar, so the engine folds the round index
     inside ``lax.scan`` — the replay path's (T, d) host block never
-    exists. Drawn in float32 and widened (exactly like the dither
-    stream): same N(0, 1) law to well below Monte-Carlo resolution at
-    half the in-scan threefry cost — fast mode never bit-matches the
-    oracle's float64 ``standard_normal`` stream anyway.
+    exists. Fast mode never bit-matches the oracle's float64
+    ``standard_normal`` stream anyway.
     """
     return jax.random.normal(jax.random.fold_in(key, t), (d,),
-                             dtype=jnp.float32).astype(jnp.float64)
+                             dtype=jnp.float32)
 
 
 def dither_base_key(seed: int, trial: int) -> jax.Array:
@@ -196,9 +209,9 @@ def fault_block(key: jax.Array, t, n: int) -> jnp.ndarray:
     Row 0 drives dropouts, row 1 erasures, row 2 stragglers
     (``core.faults.fault_masks``). ``key`` is the trial's
     :func:`fault_base_key`; ``t`` may be a traced scalar, so the engine
-    folds the round index inside ``lax.scan``. Drawn in float32; both
-    consumers widen to float64 (exact, the dither-block pattern) so they
-    compare the identical value against the float64 fault probabilities.
+    folds the round index inside ``lax.scan``. Drawn in float32 and
+    compared by both backends against the f32-rounded fault
+    probabilities (``core.faults.fault_masks``).
     """
     return jax.random.uniform(jax.random.fold_in(key, t), (3, n),
                               dtype=jnp.float32)
@@ -230,9 +243,9 @@ def participation_block(key: jax.Array, t, n: int) -> jnp.ndarray:
     ``block[m] < pi_m`` for its static inclusion probability ``pi_m``
     (``core.participation``). ``key`` is the trial's
     :func:`participate_base_key`; ``t`` may be a traced scalar, so the
-    engine folds the round index inside ``lax.scan``. Drawn in float32;
-    both consumers widen to float64 (exact, the fault-block pattern) so
-    they compare the identical value against the float64 probabilities.
+    engine folds the round index inside ``lax.scan``. Drawn in float32
+    and compared by both backends against the :func:`f32_table`-rounded
+    inclusion probabilities.
     """
     return jax.random.uniform(jax.random.fold_in(key, t), (n,),
                               dtype=jnp.float32)
@@ -268,9 +281,9 @@ def arrival_block(key: jax.Array, t, n: int) -> jnp.ndarray:
     update (compared against the device's precomputed truncated-geometric
     CDF thresholds, ``core.async_fl``). ``key`` is the trial's
     :func:`arrival_base_key`; ``t`` may be a traced scalar, so the engine
-    folds the round index inside ``lax.scan``. Drawn in float32; both
-    consumers widen to float64 (exact, the fault-block pattern) so they
-    compare the identical value against the float64 rate/CDF tables.
+    folds the round index inside ``lax.scan``. Drawn in float32 and
+    compared by both backends against the :func:`f32_table`-rounded
+    rate/CDF tables.
     """
     return jax.random.uniform(jax.random.fold_in(key, t), (2, n),
                               dtype=jnp.float32)

@@ -28,11 +28,12 @@ Two RNG execution modes (``run(..., rng=...)``):
     population-scale N / trial counts where the replay tax dominates.
 
 RNG-replay contract — the engine reproduces the NumPy trainer's random
-streams, so the two backends agree per round to ~1e-5 over hundreds of
-rounds (``tests/test_engine_parity.py``):
+streams, so the two backends agree to f32 round-off over hundreds of
+rounds (``tests/test_engine_parity.py`` documents each tolerance):
 
   * fading: ``channel.sample_fading_batch`` reproduces
-    ``FadingProcess(dep, seed*1000 + trial).sample(t)`` bit-for-bit;
+    ``FadingProcess(dep, seed*1000 + trial).sample(t)`` bit-for-bit on the
+    host; the scan consumes it rounded to complex64;
   * PS AWGN: every OTA aggregator draws exactly one ``normal(d)`` per round
     from the sequential trial rng ``default_rng((seed, trial, 17))``, so one
     ``standard_normal((T, d))`` block per trial replays the stream;
@@ -75,7 +76,7 @@ Buffered-async mode (``core.async_fl``, ``mode="async"``) runs in-scan as
 well: the scan carries a (K, N, d) last-K gradient buffer, one (2, N)
 counter-based uniform block per round (ARRIVAL_TAG — bit-identical across
 both rng modes and both backends) draws each device's delivery event and
-staleness against precomputed float64 rate/CDF tables, and the delivered
+staleness against precomputed f32-rounded rate/CDF tables, and the delivered
 payload ``delta^S * v_m * (N/sum(cv)) * g_m(w_{t-S})`` replaces the fresh
 gradient upstream of the fault layer and every scheme's combiner
 (missing devices zero-fill or replay their last delivered payload through
@@ -89,11 +90,17 @@ segment reports the last *live* model state — replicating the trainer's
 freeze-at-last-written-eval semantics exactly, including the wall-clock
 pinned at the budget-exhaustion time (``tests/test_trainer_budget.py``).
 
-Model state is carried in float64 (via the scoped x64 context) while local
-gradients/losses are computed in float32 — exactly the NumPy trainer's mixed
-precision. Caveat: dither replay assumes participating gradients are nonzero
-(``quantize_np`` skips its quantization on an exactly-zero gradient, which
-is measure-zero for the paper's tasks).
+Dtype contract: everything on the device is float32 — the model state,
+the (N, d) gradients, dither and noise, the async and stale buffers and
+every Pallas kernel operand (bf16 payloads are rounded to bf16 and
+accumulated in f32). float64 lives only on the host: the NumPy oracle and
+the replay/participation/fault/async tables, which enter the scan rounded
+to f32. The counter-based uniforms are drawn in f32 and compared against
+those f32-rounded tables by both backends, so their realizations stay
+bit-identical; trajectories agree to f32 round-off. Caveat: dither replay
+assumes participating gradients are nonzero (``quantize_np`` skips its
+quantization on an exactly-zero gradient, which is measure-zero for the
+paper's tasks).
 
 Multi-host scaling: ``FLEngine(..., shard_trials=True)`` lays the
 (embarrassingly parallel) trials axis over all visible devices with
@@ -107,7 +114,6 @@ from typing import Callable, Optional
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from ..core import async_fl
 from ..core import baselines as B
@@ -120,10 +126,11 @@ from ..core.faults import FaultSpec, fault_masks, survival_prob
 from ..core.ota import bbfl_round_jax, opc_ota_fl_round_jax, ota_round_jax
 from ..core.quantize import payload_bits
 from ..kernels import ops
+from .tasks import jit_f32
 from .trainer import TrainLog
 
-#: AggregatorFn protocol: (grads (N,d) f64, h (N,) complex, z01 (d,) f64,
-#: u (N,d) f32 dither, sel (S,) f64 replayed selection draws, t i64) ->
+#: AggregatorFn protocol: (grads (N,d) f32, h (N,) complex64, z01 (d,) f32,
+#: u (N,d) f32 dither, sel (S,) f32 replayed selection draws, t int) ->
 #: (ghat (d,), latency scalar). Latency is in channel uses for OTA schemes
 #: (converted to seconds by the engine via 1/B) and in seconds for digital
 #: schemes, matching ``core.baselines.RoundResult``. ``t`` carries the round
@@ -151,7 +158,7 @@ class JaxAggregator:
     # core.rngstream.replay_rounds); None when the scheme draws none
     sel_stream_np: Optional[Callable[[int, int, int], np.ndarray]] = None
     # fast-mode analog of sel_stream_np: (round-folded threefry key) ->
-    # (S,) float64 row with the exact layout ``round_fn`` consumes, drawn
+    # (S,) float32 row with the exact layout ``round_fn`` consumes, drawn
     # in-scan from the SELECT_TAG stream. None when the scheme draws no
     # selection randomness; a scheme with sel_stream_np but no fast
     # sampler rejects rng="fast" instead of silently diverging
@@ -230,7 +237,7 @@ def _opc_ota_comp(agg: "B.OPCOTAComp", use_kernel: bool) -> JaxAggregator:
     def round_fn(grads, h, z01, u, sel, t):
         habs = jnp.abs(h)
         n = grads.shape[0]
-        lo = jnp.maximum((b_bar * jnp.min(habs)) ** 2 * 1e-4, 1e-300)
+        lo = jnp.maximum((b_bar * jnp.min(habs)) ** 2 * 1e-4, 1e-37)
         hi = (b_bar * jnp.max(habs)) ** 2 * 1e4
         etas = jnp.geomspace(lo, hi, n_grid)                       # (n_grid,)
         b = jnp.minimum(b_bar, jnp.sqrt(etas)[:, None] / habs)     # (n_grid,N)
@@ -407,8 +414,8 @@ def _uqos(agg: "B.UQOS", use_kernel: bool) -> JaxAggregator:
         # same row layout as the replay draw: permutation then uniforms
         kp, ku = jax.random.split(key)
         return jnp.concatenate([
-            jax.random.permutation(kp, n).astype(jnp.float64),
-            jax.random.uniform(ku, (n,), dtype=jnp.float64)])
+            jax.random.permutation(kp, n).astype(jnp.float32),
+            jax.random.uniform(ku, (n,), dtype=jnp.float32)])
 
     def round_fn(grads, h, z01, u, sel, t):
         order = sel[:n].astype(jnp.int32)
@@ -449,7 +456,7 @@ def _qml(agg: "B.QML", use_kernel: bool) -> JaxAggregator:
 
     def sel_stream_jax(key):
         return jax.random.choice(key, n, (k,),
-                                 replace=False).astype(jnp.float64)
+                                 replace=False).astype(jnp.float32)
 
     def round_fn(grads, h, z01, u, sel, t):
         chi = jnp.zeros(n, grads.dtype).at[sel.astype(jnp.int32)].set(1.0)
@@ -479,7 +486,7 @@ def _fedtoe(agg: "B.FedTOE", use_kernel: bool) -> JaxAggregator:
 
     def sel_stream_jax(key):
         return jax.random.choice(key, n, (k,),
-                                 replace=False).astype(jnp.float64)
+                                 replace=False).astype(jnp.float32)
 
     def round_fn(grads, h, z01, u, sel, t):
         bits, in_alloc = greedy_bit_alloc_jax(
@@ -523,7 +530,7 @@ def as_functional(agg, use_kernel: bool = True) -> Optional[JaxAggregator]:
 
 def _project(w, radius):
     nrm = jnp.linalg.norm(w)
-    scale = jnp.minimum(1.0, radius / jnp.maximum(nrm, 1e-300))
+    scale = jnp.minimum(1.0, radius / jnp.maximum(nrm, 1e-30))
     return w * scale
 
 
@@ -626,9 +633,10 @@ class FLEngine:
         self.x_test = np.asarray(dataset.x_test, np.float32)
         self.y_test = np.asarray(dataset.y_test, np.int32)
         # built once so repeated run() calls hit the jit cache
-        self._loss_v = jax.jit(jax.vmap(task.loss_fn, in_axes=(0, None, None)))
-        self._acc_v = jax.jit(jax.vmap(task.accuracy_fn,
-                                       in_axes=(0, None, None)))
+        self._loss_v = jit_f32(jax.vmap(task.loss_fn,
+                                         in_axes=(0, None, None)))
+        self._acc_v = jit_f32(jax.vmap(task.accuracy_fn,
+                                        in_axes=(0, None, None)))
 
     @staticmethod
     def effective_batch_size(batch_size: Optional[int],
@@ -694,7 +702,7 @@ class FLEngine:
         sel_jax = jagg.sel_stream_jax
         has_sel = jagg.sel_stream_np is not None
         fast = rng_mode == "fast"
-        lambdas = jnp.asarray(self.dep.lambdas, jnp.float64)
+        lambdas = jnp.asarray(self.dep.lambdas, jnp.float32)
         # fault layer: trace-time static — with faults disabled (None) the
         # scan below is the exact pre-fault program (bit-identical runs)
         fault = self.fault
@@ -702,7 +710,7 @@ class FLEngine:
         if fault is not None:
             q_surv = jnp.asarray(
                 survival_prob(fault, np.asarray(self.dep.lambdas)),
-                jnp.float64)
+                jnp.float32)
             has_deadline = fault.deadline_s is not None
             deadline = float(fault.deadline_s) if has_deadline else np.inf
             straggler_mult = float(fault.straggler_mult)
@@ -711,22 +719,23 @@ class FLEngine:
         # pre-participation program (bit-identical runs)
         part = self.participation
         if part is not None:
-            part_probs = jnp.asarray(part.probs_array(), jnp.float64)
+            part_probs = jnp.asarray(part.probs_array(), jnp.float32)
             part_scale = float(part.scale)
         # buffered-async layer: trace-time static like the fault and
         # participation layers — with mode="sync" (None) the scan below is
         # the exact pre-async program (bit-identical runs). All tables are
-        # precomputed host-side float64, so the in-scan realization is
-        # exact comparisons/gathers only (bit-identical to the oracle).
+        # precomputed host-side float64 and rounded to f32 here; the
+        # oracle compares against the same rounded values, so the in-scan
+        # realization is exact comparisons/gathers only (bit-identical).
         asy = self.async_
         amode = asy is not None
         if amode:
             a_stale = asy.on_missing == "stale"
             a_k = asy.buffer_rounds
-            a_rates = jnp.asarray(asy.rates_array(), jnp.float64)
-            a_cdf = jnp.asarray(asy.cdf_array(), jnp.float64)
-            a_disc = jnp.asarray(asy.discounts_array(), jnp.float64)
-            a_pscale = jnp.asarray(asy.payload_scale_array(), jnp.float64)
+            a_rates = jnp.asarray(asy.rates_array(), jnp.float32)
+            a_cdf = jnp.asarray(asy.cdf_array(), jnp.float32)
+            a_disc = jnp.asarray(asy.discounts_array(), jnp.float32)
+            a_pscale = jnp.asarray(asy.payload_scale_array(), jnp.float32)
         else:
             a_stale = False
 
@@ -761,9 +770,9 @@ class FLEngine:
                     t = inp
                     h = sample_fading_jax(A, t, lambdas)
                     z = (rngstream.noise_block(B_, t, d) if needs_noise
-                         else jnp.zeros((1,), jnp.float64))
+                         else jnp.zeros((1,), jnp.float32))
                     selrow = (sel_jax(jax.random.fold_in(C, t)) if has_sel
-                              else jnp.zeros((1,), jnp.float64))
+                              else jnp.zeros((1,), jnp.float32))
                 else:
                     h, z, selrow, t = inp
                 # the trainer breaks on the first round whose *preceding*
@@ -771,8 +780,7 @@ class FLEngine:
                 # carry freezes (w and t_wall stop advancing)
                 active = t_wall < budget
                 if batch_size is None:
-                    g = grads_fn(w.astype(jnp.float32), xs, ys
-                                 ).astype(jnp.float64)
+                    g = grads_fn(w, xs, ys)
                 else:
                     # (N, B) counter-based indices regenerated in-scan —
                     # bit-identical to the oracle's batch_block_np /
@@ -781,23 +789,20 @@ class FLEngine:
                     if mixed:
                         idx = rngstream.batch_block_mixed(
                             bkey, t, device_sizes, batch_size)
-                        g = grads_fn(w.astype(jnp.float32), xs, ys, idx,
-                                     batch_wts).astype(jnp.float64)
+                        g = grads_fn(w, xs, ys, idx, batch_wts)
                     elif device_sizes is not None:
                         idx = rngstream.batch_block_ragged(
                             bkey, t, device_sizes, batch_size)
-                        g = grads_fn(w.astype(jnp.float32), xs, ys, idx
-                                     ).astype(jnp.float64)
+                        g = grads_fn(w, xs, ys, idx)
                     else:
                         idx = rngstream.batch_block(bkey, t, N, n_data,
                                                     batch_size)
-                        g = grads_fn(w.astype(jnp.float32), xs, ys, idx
-                                     ).astype(jnp.float64)
+                        g = grads_fn(w, xs, ys, idx)
                 if payload_bf16:
                     # mixed-precision uplink: the gradient payload leaves
                     # the device truncated to bf16; aggregation stays in
-                    # the engine's wide accumulators
-                    g = g.astype(jnp.bfloat16).astype(jnp.float64)
+                    # the engine's f32 accumulators
+                    g = g.astype(jnp.bfloat16).astype(jnp.float32)
                 if part is not None:
                     # Bernoulli client sampling (counter-based PARTICIPATE
                     # stream, bit-identical across backends/rng modes):
@@ -807,8 +812,8 @@ class FLEngine:
                     # combiner (non-participants keep their reserved
                     # slots, like faulted devices)
                     up = rngstream.participation_block(pkey, t, N)
-                    chi = up.astype(jnp.float64) < part_probs
-                    g = g * (chi.astype(jnp.float64) * part_scale)[:, None]
+                    chi = up < part_probs
+                    g = g * (chi.astype(jnp.float32) * part_scale)[:, None]
                 if amode:
                     # buffered-async delivery (counter-based ARRIVAL
                     # stream, bit-identical across backends/rng modes):
@@ -819,14 +824,13 @@ class FLEngine:
                     # applied upstream of the fault layer and the
                     # scheme's combiner, like the layers around it
                     ua = rngstream.arrival_block(akey, t, N)
-                    ua = ua.astype(jnp.float64)   # exact widen (x64 on)
                     g, ok_a, a_buf = async_fl.async_round(
                         g, a_buf, ua, a_rates, a_cdf, a_disc, a_pscale)
                     if a_stale:
                         g, g_alast = async_fl.stale_replace(g, ok_a,
                                                             g_alast)
                     else:
-                        g = g * ok_a.astype(jnp.float64)[:, None]
+                        g = g * ok_a.astype(jnp.float32)[:, None]
                 if fault is not None:
                     # counter-based fault draws + degradation policy,
                     # applied to the payloads *upstream* of the scheme's
@@ -834,12 +838,11 @@ class FLEngine:
                     # (faulted devices keep their reserved slots; a zeroed
                     # payload quantizes to exact zeros on both backends)
                     uf = rngstream.fault_block(fkey, t, N)
-                    uf = uf.astype(jnp.float64)   # exact widen (x64 on)
                     okb, straggler = fault_masks(uf, jnp.abs(h), fault)
                     if fault.on_missing == "zero":
-                        g = g * okb.astype(jnp.float64)[:, None]
+                        g = g * okb.astype(jnp.float32)[:, None]
                     elif fault.on_missing == "reweight":
-                        g = g * (okb.astype(jnp.float64) / q_surv)[:, None]
+                        g = g * (okb.astype(jnp.float32) / q_surv)[:, None]
                     else:
                         # stale: replay the last received gradient — the
                         # single last-gradient code path shared with the
@@ -888,22 +891,23 @@ class FLEngine:
                 w_eval = jnp.where(live, w, w_eval)
                 return (w_eval,) + inner, (w_eval, t_wall)
 
-            carry0 = (w0, w0, jnp.zeros((), jnp.float64),
+            carry0 = (w0, w0, jnp.zeros((), jnp.float32),
                       jnp.asarray(True), dkey, bkey)
             if amode:
                 # pre-start buffer slots are zeros: a staleness draw that
                 # reaches past round 0 delivers nothing (the device had
                 # not computed yet), matching the oracle exactly
-                carry0 = carry0 + (jnp.zeros((a_k, N, d), jnp.float64),)
+                carry0 = carry0 + (jnp.zeros((a_k, N, d), jnp.float32),)
                 if a_stale:
-                    carry0 = carry0 + (jnp.zeros((N, d), jnp.float64),)
+                    carry0 = carry0 + (jnp.zeros((N, d), jnp.float32),)
             if stale:
                 # until a device's first delivery, "stale" replays zeros
-                carry0 = carry0 + (jnp.zeros((N, d), jnp.float64),)
+                carry0 = carry0 + (jnp.zeros((N, d), jnp.float32),)
             seg_xs = Ts if fast else (A, B_, C, Ts)
             _, (ws, walls) = jax.lax.scan(segment, carry0, seg_xs)
             ws = jnp.concatenate([w0[None], ws], axis=0)          # (E, d)
-            walls = jnp.concatenate([jnp.zeros((1,)), walls], axis=0)
+            walls = jnp.concatenate([jnp.zeros((1,), walls.dtype), walls],
+                                    axis=0)
             return ws, walls
 
         vmapped = jax.vmap(
@@ -911,7 +915,6 @@ class FLEngine:
             in_axes=(None, None, None, None, None, None, None,
                      0, 0, 0, 0, 0, 0, 0, 0, None))
         if self.shard_trials:
-            from ..compat import shard_map as shard_map_compat
             n_hw = len(jax.devices())
             if trials % n_hw != 0:
                 raise ValueError(
@@ -919,15 +922,14 @@ class FLEngine:
                     f"device count ({n_hw})")
             mesh = jax.make_mesh((n_hw,), ("trials",))
             P = jax.sharding.PartitionSpec
-            vmapped = shard_map_compat(
-                vmapped, mesh,
+            vmapped = jax.shard_map(
+                vmapped, mesh=mesh,
                 in_specs=(P(), P(), P(), P(), P(), P(), P(),
                           P("trials"), P("trials"), P("trials"), P("trials"),
                           P("trials"), P("trials"), P("trials"), P("trials"),
                           P()),
-                out_specs=(P("trials"), P("trials")),
-                manual_axes=("trials",))
-        runner = jax.jit(vmapped)
+                out_specs=(P("trials"), P("trials")), check_vma=False)
+        runner = jit_f32(vmapped)
         jagg._runner_cache[key] = runner
         return runner
 
@@ -938,6 +940,31 @@ class FLEngine:
             w_star: Optional[np.ndarray] = None,
             time_budget_s: Optional[float] = None,
             rng: str = "replay") -> TrainLog:
+        jagg, runner, args = self.prepare(
+            aggregator, rounds=rounds, trials=trials, eval_every=eval_every,
+            seed=seed, time_budget_s=time_budget_s, rng=rng)
+        ws, walls = runner(*args)
+        losses, accs = self._evaluate(ws)
+        opt_err = (np.sum((np.asarray(ws, np.float64) - w_star) ** 2,
+                          axis=-1)
+                   if w_star is not None else None)
+        return TrainLog(scheme=jagg.name,
+                        rounds=np.arange(0, rounds + 1, eval_every,
+                                         dtype=np.int64),
+                        wall_time_s=np.asarray(walls).mean(axis=0),
+                        global_loss=np.asarray(losses, np.float64),
+                        accuracy=np.asarray(accs, np.float64),
+                        opt_error=opt_err, quantized=not jagg.is_ota)
+
+    def prepare(self, aggregator, *, rounds: int, trials: int = 3,
+                eval_every: int = 10, seed: int = 0,
+                time_budget_s: Optional[float] = None,
+                rng: str = "replay"):
+        """The jitted scan of one :meth:`run` and its arguments:
+        ``(jagg, runner, args)``. ``runner(*args)`` is the run (model
+        states and wall-clock at every eval point); ``runner.lower(*args)``
+        is its program, for counting its kernels or compiling it for a
+        described chip."""
         if rng not in ("replay", "fast"):
             raise ValueError(f"rng must be 'replay' or 'fast', got {rng!r}")
         jagg = as_functional(aggregator, use_kernel=self.use_kernel)
@@ -989,39 +1016,31 @@ class FLEngine:
         akeys = jnp.stack([rngstream.arrival_base_key(seed, tr)
                            for tr in range(trials)])
 
-        with enable_x64():
-            runner = self._get_runner(jagg, trials, n_seg, eval_every, rng)
-            w0 = jnp.asarray(self.task.init_params(), jnp.float64)
-            eta = jnp.asarray(self.eta, jnp.float64)
-            radius = jnp.asarray(
-                np.inf if self.project_radius is None else self.project_radius,
-                jnp.float64)
-            lat_div = jnp.asarray(
-                self.dep.cfg.bandwidth_hz if jagg.is_ota else 1.0,
-                jnp.float64)
-            budget = jnp.asarray(
-                np.inf if time_budget_s is None else time_budget_s,
-                jnp.float64)
-            Ts = jnp.arange(T).reshape(n_seg, eval_every)
-            if rng == "fast":
-                A, B_, C = H, Z, SEL          # per-trial base keys as-is
-            else:
-                seg = lambda a: jnp.asarray(a).reshape(
-                    (trials, n_seg, eval_every) + a.shape[2:])
-                A, B_, C = seg(H), seg(Z), seg(SEL)
-            ws, walls = runner(w0, eta, radius, lat_div, budget,
-                               jnp.asarray(self.xs), jnp.asarray(self.ys),
-                               keys, bkeys, fkeys, pkeys, akeys,
-                               A, B_, C, Ts)
-            losses, accs = self._evaluate(ws)
-            opt_err = (np.sum((np.asarray(ws) - w_star) ** 2, axis=-1)
-                       if w_star is not None else None)
-        return TrainLog(scheme=jagg.name,
-                        rounds=np.asarray(eval_rounds, dtype=np.int64),
-                        wall_time_s=np.asarray(walls).mean(axis=0),
-                        global_loss=np.asarray(losses, np.float64),
-                        accuracy=np.asarray(accs, np.float64),
-                        opt_error=opt_err)
+        runner = self._get_runner(jagg, trials, n_seg, eval_every, rng)
+        # host arrays, not device arrays: the jitted scan places each one
+        # where it runs (with shard_trials, straight onto every chip
+        # instead of staging a copy on the first)
+        f32 = np.float32
+        w0 = np.asarray(self.task.init_params(), f32)
+        eta = np.asarray(self.eta, f32)
+        radius = np.asarray(
+            np.inf if self.project_radius is None else self.project_radius,
+            f32)
+        lat_div = np.asarray(
+            self.dep.cfg.bandwidth_hz if jagg.is_ota else 1.0, f32)
+        budget = np.asarray(
+            np.inf if time_budget_s is None else time_budget_s, f32)
+        Ts = np.arange(T, dtype=np.int32).reshape(n_seg, eval_every)
+        if rng == "fast":
+            A, B_, C = H, Z, SEL          # per-trial base keys as-is
+        else:
+            # the host replay stacks enter the scan rounded to f32
+            seg = lambda a, dt: np.asarray(a.reshape(
+                (trials, n_seg, eval_every) + a.shape[2:]), dt)
+            A, B_, C = seg(H, np.complex64), seg(Z, f32), seg(SEL, f32)
+        args = (w0, eta, radius, lat_div, budget, self.xs, self.ys,
+                keys, bkeys, fkeys, pkeys, akeys, A, B_, C, Ts)
+        return jagg, runner, args
 
     def _evaluate(self, ws):
         """Global loss + test accuracy at every eval point, vmapped over

@@ -29,6 +29,20 @@ import jax
 import jax.numpy as jnp
 
 
+def jit_f32(fn):
+    """``jax.jit(fn)`` with every matmul traced at full f32 precision.
+
+    A TPU runs a default-precision f32 matmul as one bf16 pass. The f32
+    dtype contract -- and the engine's parity with the f64 oracle, whose
+    gradients come from these same programs -- needs the full product.
+    No effect on CPU.
+    """
+    def traced(*args):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args)
+    return jax.jit(traced)
+
+
 def _clip_to(g: jnp.ndarray, g_max: float) -> jnp.ndarray:
     nrm = jnp.linalg.norm(g)
     return g * jnp.minimum(1.0, g_max / jnp.maximum(nrm, 1e-12))
@@ -75,15 +89,16 @@ class SoftmaxRegressionTask:
             nll = -jnp.mean(logp[jnp.arange(x.shape[0]), y])
             return nll + 0.5 * mu * jnp.sum(w_flat ** 2)
 
-        self._loss = jax.jit(loss)
+        self._loss = jit_f32(loss)
         grad1 = jax.grad(loss)
 
         def device_grad(w_flat, x, y):
             return _clip_to(grad1(w_flat, x, y), g_max)
 
-        self._device_grads = jax.jit(jax.vmap(device_grad, in_axes=(None, 0, 0)))
-        self._device_losses = jax.jit(jax.vmap(loss, in_axes=(None, 0, 0)))
-        self._device_grads_at = jax.jit(
+        self._device_grads = jit_f32(
+            jax.vmap(device_grad, in_axes=(None, 0, 0)))
+        self._device_losses = jit_f32(jax.vmap(loss, in_axes=(None, 0, 0)))
+        self._device_grads_at = jit_f32(
             jax.vmap(_device_grad_at(device_grad), in_axes=(None, 0, 0, 0)))
 
         def loss_w(w_flat, x, y, wt):
@@ -98,7 +113,7 @@ class SoftmaxRegressionTask:
         def device_grad_w(w_flat, x, y, wt):
             return _clip_to(grad1_w(w_flat, x, y, wt), g_max)
 
-        self._device_grads_at_w = jax.jit(
+        self._device_grads_at_w = jit_f32(
             jax.vmap(_device_grad_at_weighted(device_grad_w),
                      in_axes=(None, 0, 0, 0, 0)))
 
@@ -107,7 +122,7 @@ class SoftmaxRegressionTask:
             logits = x @ W[:, :-1].T + W[:, -1]
             return jnp.mean((jnp.argmax(logits, -1) == y).astype(jnp.float32))
 
-        self._acc = jax.jit(acc)
+        self._acc = jit_f32(acc)
 
     def init_params(self, seed: int = 0) -> np.ndarray:
         return np.zeros(self.dim, dtype=np.float64)
@@ -200,14 +215,15 @@ class MLPTask:
             nll = -jnp.mean(logp[jnp.arange(x.shape[0]), y])
             return nll + 0.5 * mu_nc * jnp.sum(w_flat ** 2)
 
-        self._loss = jax.jit(loss)
+        self._loss = jit_f32(loss)
         grad1 = jax.grad(loss)
 
         def device_grad(w_flat, x, y):
             return _clip_to(grad1(w_flat, x, y), g_max)
 
-        self._device_grads = jax.jit(jax.vmap(device_grad, in_axes=(None, 0, 0)))
-        self._device_grads_at = jax.jit(
+        self._device_grads = jit_f32(
+            jax.vmap(device_grad, in_axes=(None, 0, 0)))
+        self._device_grads_at = jit_f32(
             jax.vmap(_device_grad_at(device_grad), in_axes=(None, 0, 0, 0)))
 
         def loss_w(w_flat, x, y, wt):
@@ -223,7 +239,7 @@ class MLPTask:
         def device_grad_w(w_flat, x, y, wt):
             return _clip_to(grad1_w(w_flat, x, y, wt), g_max)
 
-        self._device_grads_at_w = jax.jit(
+        self._device_grads_at_w = jit_f32(
             jax.vmap(_device_grad_at_weighted(device_grad_w),
                      in_axes=(None, 0, 0, 0, 0)))
 
@@ -232,7 +248,7 @@ class MLPTask:
             logits = jax.nn.relu(x @ W1 + b1) @ W2 + b2
             return jnp.mean((jnp.argmax(logits, -1) == y).astype(jnp.float32))
 
-        self._acc = jax.jit(acc)
+        self._acc = jit_f32(acc)
         self._unpack = unpack
 
     def init_params(self, seed: Optional[int] = None) -> np.ndarray:
@@ -329,12 +345,12 @@ class SyntheticHighDimTask:
             c = center(x[0, 0].astype(jnp.int32))
             return _clip_to(w_flat - c, g_max)
 
-        self._loss = jax.jit(loss)
-        self._device_grads = jax.jit(jax.vmap(device_grad,
+        self._loss = jit_f32(loss)
+        self._device_grads = jit_f32(jax.vmap(device_grad,
                                               in_axes=(None, 0, 0)))
-        self._device_grads_at = jax.jit(
+        self._device_grads_at = jit_f32(
             jax.vmap(_device_grad_at(device_grad), in_axes=(None, 0, 0, 0)))
-        self._acc = jax.jit(lambda w_flat, x, y: jnp.float32(0.0))
+        self._acc = jit_f32(lambda w_flat, x, y: jnp.float32(0.0))
 
     def init_params(self, seed: int = 0) -> np.ndarray:
         return np.zeros(self.dim, dtype=np.float64)

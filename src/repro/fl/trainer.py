@@ -31,6 +31,7 @@ import time
 from typing import Optional
 
 import numpy as np
+import jax
 
 from ..core import async_fl
 from ..core import participation as participation_lib
@@ -48,6 +49,7 @@ class TrainLog:
     global_loss: np.ndarray     # (trials, T_eval)
     accuracy: np.ndarray        # (trials, T_eval)
     opt_error: Optional[np.ndarray] = None   # ||w_t - w*||^2 if w* known
+    quantized: bool = False     # digital uplink: gradients were quantized
 
     def mean_std(self, field: str):
         v = getattr(self, field)
@@ -220,6 +222,17 @@ class FLTrainer:
                 "payload_dtype='bf16' needs the JAX engine, but this run "
                 "dispatches to the NumPy path (scheme "
                 f"{type(aggregator).__name__})")
+        # the oracle's task programs run on the host CPU, so that no
+        # accelerator numerics reach the reference
+        with jax.default_device(jax.devices("cpu")[0]):
+            return self._run_oracle(aggregator, rounds=rounds, trials=trials,
+                                    eval_every=eval_every, seed=seed,
+                                    w_star=w_star,
+                                    time_budget_s=time_budget_s)
+
+    def _run_oracle(self, aggregator: Aggregator, *, rounds, trials,
+                    eval_every, seed, w_star, time_budget_s) -> TrainLog:
+        """The NumPy reference loop of :meth:`run`."""
         eval_rounds = list(range(0, rounds + 1, eval_every))
         losses = np.zeros((trials, len(eval_rounds)))
         accs = np.zeros((trials, len(eval_rounds)))
@@ -238,17 +251,19 @@ class FLTrainer:
         # client sampling (counter-based PARTICIPATE stream, shared
         # bit-for-bit with the JAX engine); probabilities are static
         part = self.participation
+        # (inclusion probabilities rounded to f32 like the engine's, so
+        # both backends compare the f32 draws against the same values)
         if part is not None:
-            part_probs = part.probs_array()
+            part_probs = rngstream.f32_table(part.probs_array())
             part_scale = float(part.scale)
         # buffered-async layer (counter-based ARRIVAL stream, shared
-        # bit-for-bit with the JAX engine); the rate/CDF/discount tables
-        # are static float64, so the in-loop realization is exact
-        # comparisons/gathers only
+        # bit-for-bit with the JAX engine); the rate/CDF tables are
+        # rounded to f32 like the engine's, so the in-loop realization is
+        # exact comparisons/gathers only
         asy = self.async_
         if asy is not None:
-            a_rates = asy.rates_array()
-            a_cdf = asy.cdf_array()
+            a_rates = rngstream.f32_table(asy.rates_array())
+            a_cdf = rngstream.f32_table(asy.cdf_array())
             a_disc = asy.discounts_array()
             a_pscale = asy.payload_scale_array()
 
@@ -400,7 +415,8 @@ class FLTrainer:
         return TrainLog(scheme=aggregator.name,
                         rounds=np.asarray(eval_rounds, dtype=np.int64),
                         wall_time_s=wall.mean(axis=0), global_loss=losses,
-                        accuracy=accs, opt_error=opt_err)
+                        accuracy=accs, opt_error=opt_err,
+                        quantized=not aggregator.is_ota)
 
 
 def solve_w_star(task, x_all: np.ndarray, y_all: np.ndarray,

@@ -3,6 +3,10 @@
 ``use_kernel=False`` routes to the pure-jnp oracle (kernels/ref.py); on CPU
 the kernels execute in Pallas interpret mode, on TPU they compile to
 Mosaic. All wrappers accept arbitrary-shaped operands.
+
+Dtype contract: kernel operands are f32, or bf16 for a gradient payload;
+scalars, accumulation and results are f32 (:func:`_wide`). A 64-bit
+operand never reaches a kernel: the ``*_2d`` entry points refuse it.
 """
 from __future__ import annotations
 
@@ -11,14 +15,15 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from . import autotune, ref
+from . import ref
 from .dithered_quant import (dithered_quantize_2d, dithered_quantize_rows_2d,
-                             BLOCK_ROWS, LANES)
+                             LANES)
 from .ota_combine import ota_combine_2d
 from .linear_scan import linear_scan_fsl, CHUNK
 from .row_reduce import row_maxabs_sumsq_2d
 from .payload import (quantize_pack_rows_2d, unpack_dequant_rows_2d,
-                      packed_weighted_sum_2d, CODE_BITS_CHOICES)
+                      packed_weighted_sum_2d, CODE_BITS_CHOICES,
+                      min_block_rows)
 
 # Below this payload dimension the fused pack path is not worth the extra
 # kernel: the two-step quantize + matvec fits one or two tiles anyway and
@@ -30,63 +35,37 @@ def _on_cpu() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def _fit_block_rows(n: int) -> int:
-    """Row-tile for an n-element payload: full BLOCK_ROWS for large tensors,
-    the next power of two >= the row count for small ones (interpret-mode
-    cost scales with the padded block, so a d=7850 gradient should not pay
-    for a 512x128 tile)."""
+def _wide(dtype):
+    """Accumulation dtype for a payload dtype: f32 for f32/bf16 payloads
+    (the ``use_kernel=False`` oracle keeps an f64 input in f64)."""
+    return jnp.promote_types(dtype, jnp.float32)
+
+
+def _sublane_rows(dtype) -> int:
+    """Rows in one (sublane, 128) tile: 8 for 32-bit, 16 for bf16."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+#: Row tile of every kernel launch on a payload of BLOCK_ROWS rows or more.
+#: One constant until a chip measurement picks tiles per kernel: every
+#: kernel compiles for a v5e at it, in f32 and bf16
+#: (``tests/test_chip_compile.py``). The v5e VMEM ceilings are far above
+#: it: 4096 rows for ``ota_combine``, 2048 for the f32 dithered quantizers.
+BLOCK_ROWS = 512
+
+
+def _block_rows(n: int, dtype, min_rows: int = 8) -> int:
+    """Row tile for an n-element payload: the smallest power of two that
+    holds all its rows, capped at BLOCK_ROWS. Never below one sublane tile
+    of ``dtype`` or ``min_rows``."""
     rows = -(-n // LANES)
-    if rows >= BLOCK_ROWS:
-        return BLOCK_ROWS
-    return autotune._pow2_fit(rows)
+    br = 8
+    while br < min(rows, BLOCK_ROWS):
+        br *= 2
+    return max(br, min_rows, _sublane_rows(dtype))
 
 
-def _autotune_bench(kind: str, dtype):
-    """bench(block_rows) factory for the measured tile chooser: each kernel
-    family timed on a fixed (MEASURE_ROWS, LANES) zero slab."""
-    dtype = jax.dtypes.canonicalize_dtype(dtype)
-    rows = autotune.MEASURE_ROWS
-    interp = _on_cpu()
-
-    def bench(block_rows):
-        z = jnp.zeros((rows, LANES), dtype)
-        one = jnp.ones((), dtype)
-        if kind == "quantize":
-            scal = jnp.ones((1, 2), dtype)
-            return lambda: dithered_quantize_rows_2d(
-                z, z, scal, interpret=interp, block_rows=block_rows)
-        if kind == "ota":
-            return lambda: ota_combine_2d(
-                z, z, one, interpret=interp, block_rows=block_rows)
-        if kind == "reduce":
-            return lambda: row_maxabs_sumsq_2d(
-                z, n_dev=1, interpret=interp, block_rows=block_rows)
-        if kind == "pack":
-            scal = jnp.ones((1, 2), dtype)
-            return lambda: quantize_pack_rows_2d(
-                z, z, scal, code_bits=8, interpret=interp,
-                block_rows=block_rows)
-        if kind == "unpack":
-            p = jnp.zeros((rows // 4, LANES), jnp.uint32)
-            scal = jnp.ones((1, 3), dtype)
-            return lambda: packed_weighted_sum_2d(
-                p, scal, code_bits=8, n_dev=1, interpret=interp,
-                block_rows=block_rows)
-        raise ValueError(f"unknown autotune kind: {kind}")
-
-    return bench
-
-
-def _tuned_block_rows(kind: str, n: int, dtype) -> int:
-    """Measured replacement for the fixed BLOCK_ROWS: small payloads keep
-    the deterministic power-of-two clamp, large ones get the cached
-    autotuned tile for (kind, rows, dtype, backend)."""
-    rows = -(-n // LANES)
-    return autotune.choose_block_rows(kind, rows, dtype,
-                                      bench=_autotune_bench(kind, dtype))
-
-
-def _to_blocks(x: jnp.ndarray, block_rows: int = BLOCK_ROWS):
+def _to_blocks(x: jnp.ndarray, block_rows: int):
     """Flatten + zero-pad to (R, LANES) with R % block_rows == 0."""
     n = x.size
     per = block_rows * LANES
@@ -102,17 +81,9 @@ def _from_blocks(y2d: jnp.ndarray, n: int, shape, dtype):
 def dithered_quantize(g: jnp.ndarray, levels: jnp.ndarray, key: jax.Array,
                       *, use_kernel: bool = True) -> jnp.ndarray:
     """Dithered stochastic uniform quantize-dequantize of a full tensor."""
-    m = jnp.max(jnp.abs(g)).astype(g.dtype)
-    dither = jax.random.uniform(key, g.shape, dtype=jnp.float32).astype(g.dtype)
-    levels = jnp.asarray(levels, g.dtype)
-    if not use_kernel:
-        return ref.dithered_quantize_ref(g, m, levels, dither)
-    br = _tuned_block_rows("quantize", g.size, g.dtype)
-    g2d, n = _to_blocks(g, br)
-    u2d, _ = _to_blocks(dither, br)
-    out = dithered_quantize_2d(g2d, u2d, m, levels, interpret=_on_cpu(),
-                               block_rows=br)
-    return _from_blocks(out, n, g.shape, g.dtype)
+    dither = jax.random.uniform(key, g.shape, dtype=jnp.float32)
+    return dithered_quantize_with_dither(g, levels, dither,
+                                         use_kernel=use_kernel)
 
 
 def dithered_quantize_with_dither(g: jnp.ndarray, levels: jnp.ndarray,
@@ -121,19 +92,21 @@ def dithered_quantize_with_dither(g: jnp.ndarray, levels: jnp.ndarray,
     """Quantize-dequantize with an explicit dither operand (g's shape).
 
     Used by the FL engine, which replays the NumPy trainer's dither stream
-    for bit-parity instead of drawing from a jax PRNG key.
+    for bit-parity instead of drawing from a jax PRNG key. Returns the
+    dequantized tensor in the accumulation dtype (f32 for f32/bf16 g).
     """
-    m = jnp.max(jnp.abs(g)).astype(g.dtype)
-    levels = jnp.asarray(levels, g.dtype)
-    dither = dither.astype(g.dtype)
+    wide = _wide(g.dtype)
+    m = jnp.max(jnp.abs(g)).astype(wide)
+    levels = jnp.asarray(levels, wide)
     if not use_kernel:
-        return ref.dithered_quantize_ref(g, m, levels, dither)
-    br = _tuned_block_rows("quantize", g.size, g.dtype)
+        return ref.dithered_quantize_ref(g.astype(wide), m, levels,
+                                         dither.astype(wide))
+    br = _block_rows(g.size, g.dtype)
     g2d, n = _to_blocks(g, br)
-    u2d, _ = _to_blocks(dither, br)
+    u2d, _ = _to_blocks(dither.astype(jnp.float32), br)
     out = dithered_quantize_2d(g2d, u2d, m, levels, interpret=_on_cpu(),
                                block_rows=br)
-    return _from_blocks(out, n, g.shape, g.dtype)
+    return _from_blocks(out, n, g.shape, wide)
 
 
 def dithered_quantize_batch(gs: jnp.ndarray, levels: jnp.ndarray,
@@ -144,18 +117,21 @@ def dithered_quantize_batch(gs: jnp.ndarray, levels: jnp.ndarray,
     gs/dither: (N, d); levels: (N,) per-device 2^{r_m} - 1. Each row is
     normalized by its own ||g_m||_inf — the digital-FL uplink where every
     device compresses with its offline-designed bit-width (Sec. II-B).
+    Returns the dequantized block in the accumulation dtype.
     """
-    m = jnp.max(jnp.abs(gs), axis=1).astype(gs.dtype)
-    levels = jnp.asarray(levels, gs.dtype)
-    dither = dither.astype(gs.dtype)
+    wide = _wide(gs.dtype)
+    m = jnp.max(jnp.abs(gs), axis=1).astype(wide)
+    levels = jnp.asarray(levels, wide)
     if not use_kernel:
-        return jax.vmap(ref.dithered_quantize_ref)(gs, m, levels, dither)
+        return jax.vmap(ref.dithered_quantize_ref)(
+            gs.astype(wide), m, levels, dither.astype(wide))
     n_dev, d = gs.shape
-    br = _tuned_block_rows("quantize", d, gs.dtype)
+    br = _block_rows(d, gs.dtype)
     per = br * LANES
     d_pad = (-d) % per
     pad = lambda x: jnp.pad(x, ((0, 0), (0, d_pad))).reshape(-1, LANES)
     scal = jnp.stack([m, levels], axis=1)
+    dither = dither.astype(jnp.float32)
     out = dithered_quantize_rows_2d(pad(gs), pad(dither), scal,
                                     interpret=_on_cpu(), block_rows=br)
     return out.reshape(n_dev, d + d_pad)[:, :d]
@@ -185,7 +161,8 @@ class PackedGrads:
 
     words holds each device's quantizer codes at ``code_bits`` per entry,
     K = 32/code_bits codes per uint32 — code_bits/32 the bytes of the
-    float block it replaces. scal: (N, 2) per-device (||g||_inf, levels).
+    float block it replaces. scal: (N, 2) f32 per-device (||g||_inf,
+    levels).
     """
     words: jnp.ndarray        # (N * R_dev / K, LANES) uint32
     scal: jnp.ndarray         # (N, 2)
@@ -204,10 +181,10 @@ def quantize_pack(gs: jnp.ndarray, levels: jnp.ndarray, dither: jnp.ndarray,
     float tensor is never formed.
     """
     n_dev, d = gs.shape
-    m = jnp.max(jnp.abs(gs), axis=1).astype(gs.dtype)
-    levels = jnp.asarray(levels, gs.dtype)
-    dither = dither.astype(gs.dtype)
-    br = _tuned_block_rows("pack", d, gs.dtype)
+    m = jnp.max(jnp.abs(gs), axis=1).astype(jnp.float32)
+    levels = jnp.asarray(levels, jnp.float32)
+    dither = dither.astype(jnp.float32)
+    br = _block_rows(d, gs.dtype, min_rows=min_block_rows(code_bits))
     per = br * LANES
     d_pad = (-d) % per
     pad = lambda x: jnp.pad(x, ((0, 0), (0, d_pad))).reshape(-1, LANES)
@@ -235,7 +212,8 @@ def _dev_block(n_dev: int) -> int:
     """Devices per grid step for the fused accumulate. On CPU/interpret
     the per-grid-step overhead dominates (every step copies the operand
     buffers), so group as many whole payloads per step as divide N; on
-    TPU a multi-payload block would blow VMEM, so keep the tiled launch."""
+    TPU a multi-payload block would blow VMEM, so keep the tiled launch
+    (dev_block=1, the launch ``tests/test_chip_compile.py`` compiles)."""
     if not _on_cpu():
         return 1
     for db in (16, 8, 4, 2):
@@ -253,7 +231,7 @@ def packed_weighted_sum(pk: PackedGrads, weights: jnp.ndarray) -> jnp.ndarray:
     to the last ulp (FMA contraction) — without materializing the (N, d)
     dequantized tensor.
     """
-    w = jnp.asarray(weights, pk.scal.dtype).reshape(-1, 1)
+    w = jnp.asarray(weights, jnp.float32).reshape(-1, 1)
     scal3 = jnp.concatenate([pk.scal, w], axis=1)
     out = packed_weighted_sum_2d(pk.words, scal3, code_bits=pk.code_bits,
                                  n_dev=pk.n_dev, interpret=_on_cpu(),
@@ -288,12 +266,13 @@ def quantized_weighted_sum(gs: jnp.ndarray, levels: jnp.ndarray,
     if not fused:
         gq = dithered_quantize_batch(gs, levels, dither,
                                      use_kernel=use_kernel)
-        return jnp.asarray(weights, gs.dtype) @ gq
+        return jnp.asarray(weights, gq.dtype) @ gq
     if not use_kernel:
-        m = jnp.max(jnp.abs(gs), axis=1).astype(gs.dtype)
+        wide = _wide(gs.dtype)
+        m = jnp.max(jnp.abs(gs), axis=1).astype(wide)
         return ref.quantized_weighted_sum_ref(
-            gs, m, jnp.asarray(levels, gs.dtype), dither.astype(gs.dtype),
-            jnp.asarray(weights, gs.dtype))
+            gs.astype(wide), m, jnp.asarray(levels, wide),
+            dither.astype(wide), jnp.asarray(weights, wide))
     if cb is None:
         raise ValueError(
             f"fused quantized_weighted_sum needs a static r_max <= "
@@ -302,27 +281,26 @@ def quantized_weighted_sum(gs: jnp.ndarray, levels: jnp.ndarray,
     return packed_weighted_sum(pk, weights)
 
 
-def row_maxabs_sumsq(gs: jnp.ndarray, *, use_kernel: bool = True,
-                     acc_dtype=None):
+def row_maxabs_sumsq(gs: jnp.ndarray, *, use_kernel: bool = True):
     """Per-device gradient statistics in one fused pass.
 
-    gs: (N, d). Returns (maxabs (N,), sumsq (N,)): ``||g_m||_inf`` (the
-    quantizer scale / quantization-MSE ingredient d*maxabs^2/(2^r-1)^2)
-    and ``sum g_m^2`` (norm-based scheduling scores), computed by the
-    Pallas row-reduction kernel (interpret on CPU, Mosaic on TPU).
-    ``acc_dtype`` widens the accumulation/output above the payload dtype
-    (bf16 payloads, f32 statistics); default gs.dtype.
+    gs: (N, d) f32 or bf16. Returns (maxabs (N,), sumsq (N,)) in the
+    accumulation dtype: ``||g_m||_inf`` (the quantizer scale /
+    quantization-MSE ingredient d*maxabs^2/(2^r-1)^2) and ``sum g_m^2``
+    (norm-based scheduling scores), computed by the Pallas row-reduction
+    kernel (interpret on CPU, Mosaic on TPU). A bf16 payload accumulates
+    in f32: a bf16 sum of squares saturates after a few hundred terms.
     """
     if not use_kernel:
-        ga = gs if acc_dtype is None else gs.astype(acc_dtype)
+        ga = gs.astype(_wide(gs.dtype))
         return jnp.max(jnp.abs(ga), axis=1), jnp.sum(ga * ga, axis=1)
     n_dev, d = gs.shape
-    br = _tuned_block_rows("reduce", d, gs.dtype)
+    br = _block_rows(d, gs.dtype)
     per = br * LANES
     d_pad = (-d) % per
     g2d = jnp.pad(gs, ((0, 0), (0, d_pad))).reshape(-1, LANES)
     out = row_maxabs_sumsq_2d(g2d, n_dev=n_dev, interpret=_on_cpu(),
-                              block_rows=br, acc_dtype=acc_dtype)
+                              block_rows=br)
     return out[:, 0], out[:, 1]
 
 
@@ -343,7 +321,7 @@ def ota_combine_with_noise(g: jnp.ndarray, alpha: jnp.ndarray,
     z = noise.astype(out_dt) * inv_alpha
     if not use_kernel:
         return ref.ota_combine_ref(g.astype(out_dt), inv_alpha, z)
-    br = _tuned_block_rows("ota", g.size, g.dtype)
+    br = _block_rows(g.size, g.dtype)
     g2d, n = _to_blocks(g, br)
     z2d, _ = _to_blocks(z, br)
     out = ota_combine_2d(g2d, z2d, inv_alpha, interpret=_on_cpu(),
@@ -359,7 +337,7 @@ def ota_combine(g: jnp.ndarray, alpha: jnp.ndarray, noise_scale: jnp.ndarray,
          * jax.random.normal(key, g.shape, jnp.float32)).astype(g.dtype)
     if not use_kernel:
         return ref.ota_combine_ref(g, inv_alpha, z)
-    br = _tuned_block_rows("ota", g.size, g.dtype)
+    br = _block_rows(g.size, g.dtype)
     g2d, n = _to_blocks(g, br)
     z2d, _ = _to_blocks(z, br)
     out = ota_combine_2d(g2d, z2d, inv_alpha, interpret=_on_cpu(),

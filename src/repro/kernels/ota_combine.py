@@ -13,15 +13,17 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-BLOCK_ROWS = 512
-LANES = 128
+from .dithered_quant import (BLOCK_ROWS, LANES, as_rows, check_operands,
+                             smem_rows)
 
 
 def _kernel(scal_ref, g_ref, z_ref, o_ref):
     inv_alpha = scal_ref[0, 0]
     # the payload block may be narrower than the accumulator (bf16
     # payload, f32 accumulation): widen per-block before the arithmetic
-    o_ref[...] = g_ref[...].astype(inv_alpha.dtype) * inv_alpha + z_ref[...]
+    out = (g_ref[...].astype(jnp.float32) * inv_alpha
+           + z_ref[...].astype(jnp.float32))
+    o_ref[...] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -40,18 +42,20 @@ def ota_combine_2d(g2d: jnp.ndarray, z2d: jnp.ndarray,
     the result in f32); the payload stays narrow in HBM and widens
     per-block in VMEM. Default: g2d.dtype (unchanged legacy behavior).
     """
+    check_operands(g2d, z2d, inv_alpha)
     R = g2d.shape[0]
     out_dtype = jnp.dtype(acc_dtype) if acc_dtype is not None else g2d.dtype
-    scal = inv_alpha.astype(out_dtype).reshape(1, 1)
+    # SMEM holds 32-bit scalars only, whatever the payload dtype
+    scal = inv_alpha.astype(jnp.float32).reshape(1, 1)
     return pl.pallas_call(
         _kernel,
         grid=(R // block_rows,),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            smem_rows(1, lambda i: (0, 0)),
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(g2d.shape, out_dtype),
         interpret=interpret,
-    )(scal, g2d, z2d.astype(out_dtype))
+    )(as_rows(scal), g2d, z2d.astype(out_dtype))

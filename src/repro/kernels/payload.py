@@ -33,15 +33,22 @@ Three kernels replace it:
                               for anything that wants per-device floats.
 
 Packing layout: codes are integers in [0, levels] with levels <= 2^16 - 1
-(static ``code_bits`` in {4, 8, 16}), so K vertically-adjacent sublanes
-fold into one uint32 word via shift-or; a (block_rows, LANES) code block
-packs to (block_rows/K, LANES) words. Codes survive the float round-trip
+(static ``code_bits`` in {4, 8, 16}). A (block_rows, LANES) code block
+splits into K = 32/code_bits contiguous row chunks of block_rows/K rows;
+chunk k fills bits [k*code_bits, (k+1)*code_bits) of the
+(block_rows/K, LANES) word block. Every chunk is a whole number of
+sublane tiles (block_rows/K is a multiple of 8), so packing is
+slice-shift-or on aligned tiles. Codes survive the float round-trip
 exactly (f32 represents all integers < 2^24), so pack → unpack →
-dequantize reproduces the two-step quantizer bit-for-bit.
+dequantize reproduces the two-step quantizer bit-for-bit. Float codes
+convert to words through int32 (codes are non-negative and < 2^16, so the
+int32 → uint32 step is exact).
 
-Quantizer arithmetic matches ``dithered_quant._kernel`` operation-for-
-operation; the ``levels <= 0`` / ``m == 0`` degenerate rows (devices
-granted no bits) pack to code 0 and dequantize to exact 0.
+Quantizer arithmetic is ``dithered_quant.quantize_codes``, the two-step
+kernel's own; the ``levels <= 0`` / ``m == 0`` degenerate rows (devices
+granted no bits) pack to code 0 and dequantize to exact 0. The per-device
+scalars are f32 rows in SMEM; payload blocks may be f32 or bf16 and are
+widened to f32 in VMEM.
 """
 from __future__ import annotations
 
@@ -50,34 +57,26 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .dithered_quant import BLOCK_ROWS, LANES
+from .dithered_quant import (BLOCK_ROWS, LANES, as_rows, check_operands,
+                             quantize_codes, smem_rows)
 
 CODE_BITS_CHOICES = (4, 8, 16)
 
 
-def _quantize_codes(g, u, m, levels):
-    """Integer codes q in [0, levels], same arithmetic as the two-step
-    kernel; degenerate (levels <= 0 or m == 0) rows code to 0."""
-    valid = (levels > 0) & (m > 0)
-    safe = jnp.where(valid, 2.0 * m / jnp.where(levels > 0, levels, 1.0), 1.0)
-    x = (g + m) / safe
-    lo = jnp.floor(x)
-    up = (u < (x - lo)).astype(g.dtype)
-    q = jnp.clip(lo + up, 0.0, levels)
-    return jnp.where(valid, q, jnp.zeros_like(q))
+def min_block_rows(code_bits: int) -> int:
+    """Smallest block whose K packed chunks are whole (8, 128) tiles."""
+    return 8 * (32 // code_bits)
 
 
 def _pack_words(q_u32, code_bits):
     """(br, LANES) uint32 codes -> (br/K, LANES) packed words."""
     K = 32 // code_bits
-    if K == 1:
-        return q_u32
-    br = q_u32.shape[0]
-    qk = q_u32.reshape(br // K, K, q_u32.shape[1])
-    word = qk[:, 0, :]
+    rows = q_u32.shape[0] // K
+    word = q_u32[:rows]
     for k in range(1, K):
-        word = word | (qk[:, k, :] << (k * code_bits))
+        word = word | (q_u32[k * rows:(k + 1) * rows] << (k * code_bits))
     return word
 
 
@@ -87,14 +86,22 @@ def _unpack_words(word, code_bits):
     if K == 1:
         return word
     mask = jnp.uint32((1 << code_bits) - 1)
-    parts = [(word >> (k * code_bits)) & mask for k in range(K)]
-    q = jnp.stack(parts, axis=1)
-    return q.reshape(q.shape[0] * K, q.shape[2])
+    return jnp.concatenate(
+        [(word >> (k * code_bits)) & mask for k in range(K)], axis=0)
 
 
-def _dequant(q_u32, m, levels, dtype):
-    """Codes -> values: -m + (2m/levels) * q, degenerate rows -> 0."""
-    qf = q_u32.astype(dtype)
+def _unpack_blocks(word, code_bits, block_rows):
+    """:func:`_unpack_words` over a run of whole ``block_rows`` blocks,
+    each packed on its own (the device-blocked interpret-mode launch)."""
+    lanes = word.shape[-1]
+    blocks = word.reshape(-1, block_rows // (32 // code_bits), lanes)
+    return jax.vmap(lambda w: _unpack_words(w, code_bits))(
+        blocks).reshape(-1, lanes)
+
+
+def _dequant(q_u32, m, levels):
+    """Codes -> f32 values: -m + (2m/levels) * q, degenerate rows -> 0."""
+    qf = q_u32.astype(jnp.int32).astype(jnp.float32)
     valid = (levels > 0) & (m > 0)
     safe = jnp.where(valid, 2.0 * m / jnp.where(levels > 0, levels, 1.0), 1.0)
     return jnp.where(valid, -m + safe * qf, jnp.zeros_like(qf))
@@ -103,15 +110,17 @@ def _dequant(q_u32, m, levels, dtype):
 def _pack_kernel(scal_ref, g_ref, u_ref, o_ref, *, code_bits):
     m = scal_ref[0, 0]
     levels = scal_ref[0, 1]
-    q = _quantize_codes(g_ref[...], u_ref[...], m, levels)
-    o_ref[...] = _pack_words(q.astype(jnp.uint32), code_bits)
+    q, _, valid = quantize_codes(g_ref[...].astype(jnp.float32), u_ref[...],
+                                 m, levels)
+    q = jnp.where(valid, q, jnp.zeros_like(q))
+    o_ref[...] = _pack_words(q.astype(jnp.int32).astype(jnp.uint32),
+                             code_bits)
 
 
 def _unpack_kernel(scal_ref, p_ref, o_ref, *, code_bits):
     m = scal_ref[0, 0]
     levels = scal_ref[0, 1]
-    q = _unpack_words(p_ref[...], code_bits)
-    o_ref[...] = _dequant(q, m, levels, m.dtype)
+    o_ref[...] = _dequant(_unpack_words(p_ref[...], code_bits), m, levels)
 
 
 def _wsum_kernel(scal_ref, p_ref, o_ref, *, code_bits):
@@ -119,8 +128,7 @@ def _wsum_kernel(scal_ref, p_ref, o_ref, *, code_bits):
     m = scal_ref[0, 0]
     levels = scal_ref[0, 1]
     w = scal_ref[0, 2]
-    q = _unpack_words(p_ref[...], code_bits)
-    contrib = w * _dequant(q, m, levels, m.dtype)
+    contrib = w * _dequant(_unpack_words(p_ref[...], code_bits), m, levels)
 
     @pl.when(dev == 0)
     def _init():
@@ -132,7 +140,7 @@ def _wsum_kernel(scal_ref, p_ref, o_ref, *, code_bits):
 
 
 def _wsum_devblock_kernel(scal_ref, p_ref, o_ref, *, code_bits, dev_block,
-                          rp_words):
+                          rp_words, block_rows):
     """Device-blocked variant: one grid step accumulates ``dev_block``
     whole device payloads (``rp_words`` packed rows each). Grid-step
     overhead dominates the revisited-accumulator pattern (in interpret
@@ -149,9 +157,9 @@ def _wsum_devblock_kernel(scal_ref, p_ref, o_ref, *, code_bits, dev_block,
         m = scal_ref[k, 0]
         levels = scal_ref[k, 1]
         w = scal_ref[k, 2]
-        q = _unpack_words(p_ref[k * rp_words:(k + 1) * rp_words, :],
-                          code_bits)
-        o_ref[...] = o_ref[...] + w * _dequant(q, m, levels, m.dtype)
+        q = _unpack_blocks(p_ref[k * rp_words:(k + 1) * rp_words, :],
+                           code_bits, block_rows)
+        o_ref[...] = o_ref[...] + w * _dequant(q, m, levels)
 
 
 @functools.partial(jax.jit,
@@ -166,26 +174,29 @@ def quantize_pack_rows_2d(g2d: jnp.ndarray, u2d: jnp.ndarray,
     scal: (N, 2) per-device (m_i, levels_i) with levels_i <= 2^code_bits-1.
     Returns (N*R_dev/K, LANES) uint32, K = 32 // code_bits.
     """
+    check_operands(g2d, u2d, scal)
+    if block_rows % min_block_rows(code_bits):
+        raise ValueError(f"block_rows={block_rows} must be a multiple of "
+                         f"{min_block_rows(code_bits)} for {code_bits}-bit "
+                         "codes")
     NR = g2d.shape[0]
     n_dev = scal.shape[0]
     r_dev = NR // n_dev
     blocks_per_dev = r_dev // block_rows
     K = 32 // code_bits
+    rows = lambda i, j, b=blocks_per_dev: (i * b + j, 0)
     return pl.pallas_call(
         functools.partial(_pack_kernel, code_bits=code_bits),
         grid=(n_dev, blocks_per_dev),
         in_specs=[
-            pl.BlockSpec((1, 2), lambda i, j: (i, 0)),       # device scalars
-            pl.BlockSpec((block_rows, LANES),
-                         lambda i, j, b=blocks_per_dev: (i * b + j, 0)),
-            pl.BlockSpec((block_rows, LANES),
-                         lambda i, j, b=blocks_per_dev: (i * b + j, 0)),
+            smem_rows(2, lambda i, j: (i, 0)),
+            pl.BlockSpec((block_rows, LANES), rows),
+            pl.BlockSpec((block_rows, LANES), rows),
         ],
-        out_specs=pl.BlockSpec((block_rows // K, LANES),
-                               lambda i, j, b=blocks_per_dev: (i * b + j, 0)),
+        out_specs=pl.BlockSpec((block_rows // K, LANES), rows),
         out_shape=jax.ShapeDtypeStruct((NR // K, LANES), jnp.uint32),
         interpret=interpret,
-    )(scal, g2d, u2d)
+    )(as_rows(scal), g2d, u2d)
 
 
 @functools.partial(jax.jit,
@@ -197,26 +208,26 @@ def unpack_dequant_rows_2d(p2d: jnp.ndarray, scal: jnp.ndarray,
                            block_rows: int = BLOCK_ROWS) -> jnp.ndarray:
     """Inverse of quantize_pack_rows_2d: packed words -> dequantized floats.
 
-    p2d: (N*R_dev/K, LANES) uint32; scal: (N, 2) per-device (m, levels).
-    Returns (N*R_dev, LANES) in scal.dtype — the materializing decoder.
+    p2d: (N*R_dev/K, LANES) uint32; scal: (N, 2) f32 per-device
+    (m, levels). Returns (N*R_dev, LANES) f32 — the materializing decoder.
     """
+    check_operands(p2d, scal)
     K = 32 // code_bits
     NR = p2d.shape[0] * K
     r_dev = NR // n_dev
     blocks_per_dev = r_dev // block_rows
+    rows = lambda i, j, b=blocks_per_dev: (i * b + j, 0)
     return pl.pallas_call(
         functools.partial(_unpack_kernel, code_bits=code_bits),
         grid=(n_dev, blocks_per_dev),
         in_specs=[
-            pl.BlockSpec((1, 2), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_rows // K, LANES),
-                         lambda i, j, b=blocks_per_dev: (i * b + j, 0)),
+            smem_rows(2, lambda i, j: (i, 0)),
+            pl.BlockSpec((block_rows // K, LANES), rows),
         ],
-        out_specs=pl.BlockSpec((block_rows, LANES),
-                               lambda i, j, b=blocks_per_dev: (i * b + j, 0)),
-        out_shape=jax.ShapeDtypeStruct((NR, LANES), scal.dtype),
+        out_specs=pl.BlockSpec((block_rows, LANES), rows),
+        out_shape=jax.ShapeDtypeStruct((NR, LANES), jnp.float32),
         interpret=interpret,
-    )(scal, p2d)
+    )(as_rows(scal), p2d)
 
 
 @functools.partial(jax.jit,
@@ -245,6 +256,7 @@ def packed_weighted_sum_2d(p2d: jnp.ndarray, scal: jnp.ndarray,
     the entire cost. Block = dev_block whole payloads, so it is
     CPU/interpret territory; TPU launches keep dev_block=1 and tile.
     """
+    check_operands(p2d, scal)
     K = 32 // code_bits
     NR = p2d.shape[0] * K
     r_dev = NR // n_dev
@@ -252,15 +264,17 @@ def packed_weighted_sum_2d(p2d: jnp.ndarray, scal: jnp.ndarray,
         rp_words = r_dev // K
         return pl.pallas_call(
             functools.partial(_wsum_devblock_kernel, code_bits=code_bits,
-                              dev_block=dev_block, rp_words=rp_words),
+                              dev_block=dev_block, rp_words=rp_words,
+                              block_rows=block_rows),
             grid=(n_dev // dev_block,),
             in_specs=[
-                pl.BlockSpec((dev_block, 3), lambda mb: (mb, 0)),
+                pl.BlockSpec((dev_block, 3), lambda mb: (mb, 0),
+                             memory_space=pltpu.SMEM),
                 pl.BlockSpec((dev_block * rp_words, LANES),
                              lambda mb: (mb, 0)),
             ],
             out_specs=pl.BlockSpec((r_dev, LANES), lambda mb: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((r_dev, LANES), scal.dtype),
+            out_shape=jax.ShapeDtypeStruct((r_dev, LANES), jnp.float32),
             interpret=interpret,
         )(scal, p2d)
     blocks_per_dev = r_dev // block_rows
@@ -268,11 +282,11 @@ def packed_weighted_sum_2d(p2d: jnp.ndarray, scal: jnp.ndarray,
         functools.partial(_wsum_kernel, code_bits=code_bits),
         grid=(blocks_per_dev, n_dev),
         in_specs=[
-            pl.BlockSpec((1, 3), lambda i, m: (m, 0)),       # device scalars
+            smem_rows(3, lambda i, m: (m, 0)),
             pl.BlockSpec((block_rows // K, LANES),
                          lambda i, m, b=blocks_per_dev: (m * b + i, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, LANES), lambda i, m: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r_dev, LANES), scal.dtype),
+        out_shape=jax.ShapeDtypeStruct((r_dev, LANES), jnp.float32),
         interpret=interpret,
-    )(scal, p2d)
+    )(as_rows(scal), p2d)

@@ -11,8 +11,8 @@ the (N, 2) statistics instead of two full passes.
 Layout matches ``dithered_quant.dithered_quantize_rows_2d``: the caller
 flattens/pads each device's gradient to ``r_dev`` rows of 128 lanes and
 stacks devices; the grid walks (device, row-block) with the row-block axis
-innermost, accumulating into the (1, 2) output block that every j-step of
-device i revisits.
+innermost, accumulating into the (1, 2) f32 SMEM output row that every
+j-step of device i revisits.
 """
 from __future__ import annotations
 
@@ -22,14 +22,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .dithered_quant import BLOCK_ROWS, LANES
+from .dithered_quant import BLOCK_ROWS, LANES, check_operands, smem_rows
 
 
-def _kernel(g_ref, o_ref, *, acc_dtype):
+def _kernel(g_ref, o_ref):
     j = pl.program_id(1)
     # widen the payload block before reducing (bf16 payload, f32 stats):
     # a bf16 sum-of-squares saturates after a few hundred terms
-    g = g_ref[...].astype(acc_dtype)
+    g = g_ref[...].astype(jnp.float32)
     pmax = jnp.max(jnp.abs(g))
     psum = jnp.sum(g * g)
 
@@ -45,31 +45,28 @@ def _kernel(g_ref, o_ref, *, acc_dtype):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n_dev", "interpret", "block_rows",
-                                    "acc_dtype"))
+                   static_argnames=("n_dev", "interpret", "block_rows"))
 def row_maxabs_sumsq_2d(g2d: jnp.ndarray, n_dev: int = None,
                         interpret: bool = False,
-                        block_rows: int = BLOCK_ROWS,
-                        acc_dtype=None) -> jnp.ndarray:
+                        block_rows: int = BLOCK_ROWS) -> jnp.ndarray:
     """g2d: (N*R_dev, LANES), device i owning rows [i*R_dev, (i+1)*R_dev).
 
-    Returns (N, 2): column 0 = max|g_i|, column 1 = sum g_i^2 per device.
-    Zero padding is inert for both statistics. ``acc_dtype`` widens the
-    accumulate/output dtype above the payload dtype (bf16 payload, f32
-    statistics); default g2d.dtype.
+    Returns (N, 2) f32: column 0 = max|g_i|, column 1 = sum g_i^2 per
+    device, accumulated in f32 whatever the payload dtype (f32 or bf16).
+    Zero padding is inert for both statistics.
     """
+    check_operands(g2d)
     NR = g2d.shape[0]
-    out_dtype = jnp.dtype(acc_dtype) if acc_dtype is not None else g2d.dtype
     r_dev = NR // n_dev
     blocks_per_dev = r_dev // block_rows
     return pl.pallas_call(
-        functools.partial(_kernel, acc_dtype=out_dtype),
+        _kernel,
         grid=(n_dev, blocks_per_dev),
         in_specs=[
             pl.BlockSpec((block_rows, LANES),
                          lambda i, j, b=blocks_per_dev: (i * b + j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 2), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_dev, 2), out_dtype),
+        out_specs=smem_rows(2, lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_dev, 1, 2), jnp.float32),
         interpret=interpret,
-    )(g2d)
+    )(g2d).reshape(n_dev, 2)

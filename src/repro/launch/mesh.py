@@ -11,12 +11,13 @@ Target hardware (roofline constants live in benchmarks/roofline.py):
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from ..compat import make_auto_mesh
 
-
-def _mesh(shape, axes):
-    return make_auto_mesh(shape, axes)
+def auto_mesh(shape, axes):
+    """Mesh over the visible devices with every axis in Auto mode."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False, data_axis=None):
@@ -31,7 +32,7 @@ def make_production_mesh(*, multi_pod: bool = False, data_axis=None):
     model = chips // data
     shape = (2, data, model) if multi_pod else (data, model)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(model_axis: int = 1, data_axis: int = 1,
@@ -40,8 +41,8 @@ def make_host_mesh(model_axis: int = 1, data_axis: int = 1,
     n = len(jax.devices())
     data_axis = min(data_axis, n // model_axis) or 1
     if multi_pod:
-        return _mesh((1, data_axis, model_axis), ("pod", "data", "model"))
-    return _mesh((data_axis, model_axis), ("data", "model"))
+        return auto_mesh((1, data_axis, model_axis), ("pod", "data", "model"))
+    return auto_mesh((data_axis, model_axis), ("data", "model"))
 
 
 def client_axes(mesh) -> tuple:

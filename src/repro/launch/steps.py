@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .. import compat
 from ..core.collectives import WirelessRound, wireless_psum
 from ..models import api
 from ..models.transformer import Transformer
@@ -169,11 +168,11 @@ def make_train_step(model: Transformer, mesh: Mesh, *,
         loss_mean = jax.lax.psum(loss, caxes) / nc
         return new_params, loss_mean
 
-    shard_body = compat.shard_map(
-        body, mesh,
+    shard_body = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(pspecs_manual, bspecs, fl_specs, P()),
         out_specs=(pspecs_manual, P()),
-        manual_axes=caxes)
+        axis_names=set(caxes), check_vma=False)
 
     in_sh = (_named(mesh, pspecs), _named(mesh, bspecs),
              _named(mesh, fl_specs), NamedSharding(mesh, P()))

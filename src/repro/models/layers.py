@@ -308,11 +308,11 @@ def moe_apply(cfg: ModelConfig, p: dict, x: jnp.ndarray,
             from jax.sharding import PartitionSpec as P
             pspecs = {"router": P(), "w_gate": P(axis), "w_up": P(axis),
                       "w_down": P(axis)}
-            from ..compat import shard_map as _shard_map
-            fn = _shard_map(
+            fn = jax.shard_map(
                 lambda p_, x_: _moe_apply_ep(cfg, p_, x_, axis, quant=quant),
-                mesh, in_specs=(pspecs, P(axis)),
-                out_specs=(P(axis), P()), manual_axes=(axis,))
+                mesh=mesh, in_specs=(pspecs, P(axis)),
+                out_specs=(P(axis), P()), axis_names={axis},
+                check_vma=False)
             return fn(p, x)
         # fall through to auto when EP preconditions fail
     C = _capacity(cfg, T)

@@ -45,13 +45,14 @@ from repro.data.loader import FLDataset
 from repro.data.partition import partition_by_class
 from repro.data.synthetic import SyntheticSpec, make_classification_dataset
 from repro.fl.tasks import SoftmaxRegressionTask
+from repro.fl.parity import assert_parity
 from repro.fl.trainer import FLTrainer, solve_w_star
 
 N_DEVICES = 10
 ROUNDS = 20
 TRIALS = 2
 EVAL_EVERY = 5
-TOL = dict(rtol=1e-5, atol=1e-5)
+N_TEST = 10 * 30     # 10 classes x n_test_per_class
 
 ASPEC = A.AsyncSpec(buffer_rounds=3, arrival_rate=0.6,
                     rate_heterogeneity=2.0, staleness_discount=0.8)
@@ -371,9 +372,7 @@ def _run(setup, agg, *, backend, rng="replay", trainer_kw=None, rounds=ROUNDS,
 
 
 def _assert_logs_match(log_np, log_jx):
-    np.testing.assert_array_equal(log_np.rounds, log_jx.rounds)
-    np.testing.assert_allclose(log_jx.global_loss, log_np.global_loss, **TOL)
-    np.testing.assert_allclose(log_jx.accuracy, log_np.accuracy, **TOL)
+    assert_parity(log_np, log_jx, n_test=N_TEST)
 
 
 class TestEngineOracleParity:

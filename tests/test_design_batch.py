@@ -12,8 +12,7 @@ import pytest
 from repro.core.bounds import ObjectiveWeights
 from repro.core.channel import WirelessConfig, make_deployment
 from repro.core import digital_design, ota_design
-
-PARITY_RTOL = 1e-3
+from repro.core.sca_jax import ORACLE_RTOL as PARITY_RTOL
 
 # The SciPy SCA oracle must run clean: re-anchored starts are clipped into
 # the SLSQP box (core.sca.solve_surrogate) and the solver's internal

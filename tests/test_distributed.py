@@ -15,23 +15,10 @@ import pytest
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.launch.sharding import ShardingRules, decode_rules
 from repro.launch.hlo_cost import analyze_hlo, parse_computations
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-# Partial-auto shard_map (client axes manual, "model" axis automatic) hits
-# an XLA SPMD partitioner check ("IsManualSubgroup") on jax<=0.4.x; the
-# compat shim covers the API surface but not that compiler bug, so the
-# mixed-mode train step needs a current jax. Gate on the *version* (the
-# bug is fixed in 0.5+), not on where shard_map lives — the old spelling
-# over-skipped on every jax that still exports the experimental path.
-requires_current_shard_map = pytest.mark.skipif(
-    not compat.HAS_PARTIAL_AUTO_SHARD_MAP,
-    reason=f"partial-auto shard_map miscompiles on jax<=0.4.x "
-           f"(XLA IsManualSubgroup check; running {compat.JAX_VERSION})")
-
 
 def run_sub(code: str, devices: int = 8) -> str:
     env = dict(os.environ)
@@ -120,15 +107,14 @@ class TestHLOCost:
 
 @pytest.mark.slow
 class TestMultiDevice:
-    @requires_current_shard_map
     def test_train_step_aggregators(self):
         out = run_sub("""
             import jax, jax.numpy as jnp, numpy as np
-            from repro.compat import make_auto_mesh
             from repro.configs import get_config
             from repro.models import make_model, make_batch
+            from repro.launch.mesh import auto_mesh
             from repro.launch.steps import make_train_step, fl_round_arrays
-            mesh = make_auto_mesh((4,2), ("data","model"))
+            mesh = auto_mesh((4,2), ("data","model"))
             cfg = get_config("qwen3-moe-30b-a3b").scaled_down()
             model = make_model(cfg)
             params = model.init(jax.random.key(0))
@@ -155,9 +141,9 @@ class TestMultiDevice:
         out = run_sub("""
             import jax, jax.numpy as jnp, numpy as np
             from jax.sharding import PartitionSpec as P
-            from repro.compat import make_auto_mesh, shard_map
             from repro.core.collectives import WirelessRound, wireless_psum
-            mesh = make_auto_mesh((4,), ("data",))
+            from repro.launch.mesh import auto_mesh
+            mesh = auto_mesh((4,), ("data",))
             grads = np.arange(4 * 6, dtype=np.float32).reshape(4, 6)
             weight = np.array([0.5, 0.0, 1.5, 1.0], np.float32)
             alpha = 2.5
@@ -167,9 +153,10 @@ class TestMultiDevice:
                                   levels=jnp.float32(255.0))
                 return wireless_psum({"g": g[0]}, r, ("data",), key,
                                      mode="ota", use_kernel=False)["g"]
-            f = shard_map(body, mesh,
-                          in_specs=(P("data"), P("data"), P()),
-                          out_specs=P(), manual_axes=("data",))
+            f = jax.shard_map(body, mesh=mesh,
+                              in_specs=(P("data"), P("data"), P()),
+                              out_specs=P(), axis_names={"data"},
+                              check_vma=False)
             got = jax.jit(f)(jnp.asarray(grads).reshape(4, 1, 6),
                              jnp.asarray(weight), jax.random.key(0))
             want = (weight[:, None] * grads).sum(0) / alpha
@@ -182,11 +169,11 @@ class TestMultiDevice:
     def test_decode_step_multidevice(self):
         out = run_sub("""
             import jax, numpy as np
-            from repro.compat import make_auto_mesh
             from repro.configs import get_config
             from repro.models import make_model
+            from repro.launch.mesh import auto_mesh
             from repro.launch.steps import make_decode_step
-            mesh = make_auto_mesh((4,2), ("data","model"))
+            mesh = auto_mesh((4,2), ("data","model"))
             for arch in ("gemma3-4b", "falcon-mamba-7b"):
                 cfg = get_config(arch).scaled_down()
                 model = make_model(cfg)

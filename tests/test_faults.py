@@ -28,9 +28,11 @@ from repro.core.faults import (FaultSpec, effective_lambdas, fault_masks,
 from repro.data.loader import FLDataset
 from repro.data.partition import partition_by_class
 from repro.data.synthetic import SyntheticSpec, make_classification_dataset
+from repro.fl.parity import WALL_RTOL, assert_parity
 from repro.fl.trainer import FLTrainer
 
 N_DEVICES = 10
+N_TEST = 10 * 30     # 10 classes x n_test_per_class
 
 
 @pytest.fixture(scope="module")
@@ -113,10 +115,7 @@ class TestPolicyParity:
         agg = _vanilla(setup)
         log_np = _run(setup, agg, f, backend="numpy")
         log_jx = _run(setup, agg, f, backend="jax")
-        np.testing.assert_allclose(log_jx.global_loss, log_np.global_loss,
-                                   rtol=1e-6, atol=1e-9)
-        np.testing.assert_allclose(log_jx.wall_time_s, log_np.wall_time_s,
-                                   rtol=1e-10)
+        assert_parity(log_np, log_jx, n_test=N_TEST)
 
     def test_deadline_caps_latency_on_both_backends(self, setup):
         f = FaultSpec(dropout_prob=0.2, straggler_prob=0.3,
@@ -125,7 +124,7 @@ class TestPolicyParity:
         log_np = _run(setup, agg, f, backend="numpy", trials=1)
         log_jx = _run(setup, agg, f, backend="jax", trials=1)
         np.testing.assert_allclose(log_jx.wall_time_s, log_np.wall_time_s,
-                                   rtol=1e-10)
+                                   rtol=WALL_RTOL)
         # every round costs at most the deadline
         assert log_np.wall_time_s[-1] <= 12 * 1e-4 + 1e-12
 

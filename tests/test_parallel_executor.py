@@ -17,6 +17,7 @@ start method, which re-imports ``__main__`` in each worker.)
 """
 import json
 
+import jax
 import pytest
 
 from repro.api import ScenarioSpec, SweepSpec, execute, plan
@@ -135,8 +136,8 @@ def test_jobs_validation(tmp_path):
 
 
 def test_chaos_worker_kill_is_recovered(tmp_path, monkeypatch):
-    """SIGKILL one worker mid-cell (env-gated chaos hook, fires exactly
-    once): the supervisor requeues the cell on a fresh worker and the
+    """SIGKILL one worker holding a cell (env-gated chaos hook, fires
+    exactly once): the supervisor requeues the cell on a fresh worker and the
     sweep completes with a manifest identical to the serial run."""
     sweep = _grid()
     rs_ser = execute(sweep, out_dir=tmp_path / "serial")
@@ -225,3 +226,19 @@ def test_schedule_orders_designs_before_dependent_cells():
             seen_cells.add(item.index)
     assert len(solved) == len(pl.design_groups)
     assert len(seen_cells) == len(pl.cells)
+
+
+def test_parallel_refused_on_accelerator(tmp_path, monkeypatch):
+    """On an accelerator backend the pool would spawn workers that each
+    need the chip, which one process already holds: ``jobs>1`` raises
+    before any worker (or any cell) starts."""
+    import multiprocessing
+
+    def no_spawn(*a, **k):
+        raise AssertionError("a worker was spawned")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(multiprocessing, "get_context", no_spawn)
+    with pytest.raises(RuntimeError, match="jobs=1"):
+        execute(_tiny(), out_dir=tmp_path / "r", jobs=2)
+    assert not (tmp_path / "r").exists()

@@ -367,3 +367,28 @@ def test_cell_payloads_are_schema_versioned(tmp_path):
     assert payload["kind"] == "scenario_cell"
     on_disk = json.loads(rs.cell(0).path.read_text())
     assert on_disk == json.loads(dump_json(payload))   # tuples -> lists
+
+
+def test_benchmark_runner_exits_nonzero_on_failed_suite(monkeypatch, capsys):
+    """A suite that raises prints its ERROR row, the other suites still
+    run, and the harness exits 1 instead of reporting success."""
+    import sys
+    import types
+
+    import benchmarks.run as runner
+
+    def boom(quick):
+        raise RuntimeError("suite broke")
+
+    suites = {"good": types.SimpleNamespace(
+                  run=lambda quick: ([("good/x", 1.0, "d")], {})),
+              "bad": types.SimpleNamespace(run=boom)}
+    monkeypatch.setattr(runner, "_registry", lambda: suites)
+    monkeypatch.setattr(runner.compile_cache, "enable", lambda: "")
+    monkeypatch.setattr(sys, "argv", ["benchmarks.run"])
+    with pytest.raises(SystemExit) as exc:
+        runner.main()
+    assert exc.value.code == 1
+    out = capsys.readouterr().out
+    assert "bad,0,ERROR:RuntimeError:suite broke" in out
+    assert "good/TOTAL" in out

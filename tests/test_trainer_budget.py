@@ -16,6 +16,7 @@ from repro.core.channel import WirelessConfig, make_deployment
 from repro.data.loader import FLDataset
 from repro.data.partition import partition_by_class
 from repro.data.synthetic import SyntheticSpec, make_classification_dataset
+from repro.fl.parity import WALL_RTOL
 from repro.fl.tasks import SoftmaxRegressionTask
 from repro.fl.trainer import FLTrainer
 
@@ -58,9 +59,11 @@ def test_budget_trips_mid_grid_freezes_last_written(setup, backend):
             assert log.accuracy[trial, j] == log.accuracy[trial, 0]
             assert log.opt_error[trial, j] == log.opt_error[trial, 0]
     assert np.all(np.isfinite(log.global_loss))
-    # frozen wall-clock records when the budget tripped (2 rounds elapsed)
+    # frozen wall-clock records when the budget tripped (2 rounds elapsed):
+    # the f64 oracle's sum is exact, the engine's is an f32 sum
     np.testing.assert_allclose(np.asarray(log.wall_time_s)[1:],
-                               2 * per_round, rtol=1e-12)
+                               2 * per_round,
+                               rtol=1e-12 if backend == "numpy" else WALL_RTOL)
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
@@ -108,7 +111,7 @@ def test_jax_backend_accepts_budget_and_minibatch(setup):
     assert log.global_loss[0, 2] == log.global_loss[0, 1]
     assert log.global_loss[0, 1] != log.global_loss[0, 0]
     np.testing.assert_allclose(np.asarray(log.wall_time_s)[-1],
-                               4 * per_round, rtol=1e-12)
+                               4 * per_round, rtol=WALL_RTOL)
 
 
 def test_engine_budget_freeze_matches_oracle_exactly(setup):
