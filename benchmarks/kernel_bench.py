@@ -5,12 +5,12 @@ target is TPU Mosaic, see kernels/*.py docstrings).
 ``--payload`` runs the payload-scale suite instead: the fused
 quantize->pack->dequant-aggregate pipeline at N=256 devices, d=10^6
 (full mode adds d=10^7) against the materialize-then-sum baseline, with
-per-kernel achieved bytes/s and FLOP/s vs the ``benchmarks.roofline``
-peaks, and the bf16-payload/f32-accumulate kernel rows. Writes the schema-stamped record to
-the repo-root ``BENCH_kernel_payload.json`` (tracked across PRs, next to
-``BENCH_engine_scale.json``). ``--rss-budget-mb`` guards the fused
-phase's peak RSS (exit 1 on overrun — the scripts/verify.sh CI gate that
-pins the O(d) aggregation claim)."""
+per-kernel achieved bytes/s and FLOP/s vs one TPU v5e chip's peaks, and
+the bf16-payload/f32-accumulate kernel rows. Writes the schema-stamped
+record to the repo-root ``BENCH_kernel_payload.json`` (tracked across
+PRs, next to ``BENCH_engine_scale.json``). ``--rss-budget-mb`` guards the
+fused phase's peak RSS (exit 1 on overrun — the scripts/verify.sh CI gate
+that pins the O(d) aggregation claim)."""
 from __future__ import annotations
 
 import argparse
@@ -93,10 +93,14 @@ def _time_s(fn, *args, reps=2):
     return best
 
 
+#: one TPU v5e chip: bf16 FLOP/s and HBM bytes/s (Google Cloud, "TPU v5e")
+PEAK_FLOPS = 197e12
+HBM_BW = 819e9
+
+
 def _roofline_fracs(bytes_moved: float, flops: float, secs: float) -> dict:
     """Achieved throughput vs the roofline peaks (indicative on CPU
     interpret; the fractions become meaningful on TPU Mosaic)."""
-    from .roofline import HBM_BW, PEAK_FLOPS
     return {
         "bytes": bytes_moved, "flops": flops, "wall_s": secs,
         "achieved_bytes_per_s": bytes_moved / secs,

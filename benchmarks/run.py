@@ -1,4 +1,4 @@
-"""Benchmark harness: one module per paper table/figure + roofline.
+"""Benchmark harness: one module per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV rows. Full payloads are saved to
 experiments/results/*.json.
@@ -22,13 +22,12 @@ from repro import compile_cache
 
 
 def _registry() -> dict:
-    from . import (fig2_ota_sc, fig2_digital_sc, fig3_nonconvex, roofline,
+    from . import (fig2_ota_sc, fig2_digital_sc, fig3_nonconvex,
                    kernel_bench, theorem_validation, engine_bench,
                    design_bench, sweep_snr_het, sweep_fault,
                    sweep_participation, sweep_async)
     return {
         "kernel_bench": kernel_bench,
-        "roofline": roofline,
         "theorem_validation": theorem_validation,
         "engine_bench": engine_bench,
         # the SGD mini-batch + time-budget engine suite shares the module
@@ -99,7 +98,7 @@ def main() -> None:
     for name, mod in modules.items():
         t0 = time.time()
         try:
-            rows, payload = mod.run(quick=quick)
+            rows, _ = mod.run(quick=quick)
         except Exception as e:
             # report and go on to the next suite, but the run fails
             print(f"{name},0,ERROR:{type(e).__name__}:{e}", flush=True)
@@ -108,8 +107,6 @@ def main() -> None:
         for r in rows:
             print(f"{r[0]},{r[1]:.1f},{r[2]}", flush=True)
         print(f"{name}/TOTAL,{(time.time() - t0) * 1e6:.0f},ok", flush=True)
-        if name == "roofline" and payload.get("table"):
-            print(mod.format_table(payload), file=sys.stderr)
     if failed:
         print(f"{len(failed)} suite(s) failed: {', '.join(failed)}",
               file=sys.stderr)

@@ -528,6 +528,17 @@ def as_functional(agg, use_kernel: bool = True) -> Optional[JaxAggregator]:
 
 # ----------------------------------------------------------------- engine
 
+def _host_bytes(*arrays) -> int:
+    """Bytes of the NumPy arrays among ``arrays``: what a jitted call that
+    takes them copies from the host to the device."""
+    return sum(a.nbytes for a in arrays if isinstance(a, np.ndarray))
+
+
+#: the scan's arguments batched over trials (the vmap axis; with
+#: ``shard_trials``, split over the chips): the per-trial keys and streams
+_TRIAL_AXES = (None,) * 7 + (0,) * 8 + (None,)
+
+
 def _project(w, radius):
     nrm = jnp.linalg.norm(w)
     scale = jnp.minimum(1.0, radius / jnp.maximum(nrm, 1e-30))
@@ -768,25 +779,27 @@ class FLEngine:
                     g_stale = ext.pop(0)
                 if fast:
                     t = inp
-                    h = sample_fading_jax(A, t, lambdas)
-                    z = (rngstream.noise_block(B_, t, d) if needs_noise
-                         else jnp.zeros((1,), jnp.float32))
-                    selrow = (sel_jax(jax.random.fold_in(C, t)) if has_sel
-                              else jnp.zeros((1,), jnp.float32))
+                    with jax.named_scope("fl.streams"):
+                        h = sample_fading_jax(A, t, lambdas)
+                        z = (rngstream.noise_block(B_, t, d) if needs_noise
+                             else jnp.zeros((1,), jnp.float32))
+                        selrow = (sel_jax(jax.random.fold_in(C, t))
+                                  if has_sel
+                                  else jnp.zeros((1,), jnp.float32))
                 else:
                     h, z, selrow, t = inp
                 # the trainer breaks on the first round whose *preceding*
                 # cumulative wall-clock hit the budget; past that round the
                 # carry freezes (w and t_wall stop advancing)
                 active = t_wall < budget
-                if batch_size is None:
-                    g = grads_fn(w, xs, ys)
-                else:
+                with jax.named_scope("fl.grads"):
+                    if batch_size is None:
+                        g = grads_fn(w, xs, ys)
                     # (N, B) counter-based indices regenerated in-scan —
                     # bit-identical to the oracle's batch_block_np /
                     # batch_indices_np draws (ragged rows key on each
                     # device's own size and never hit the padding)
-                    if mixed:
+                    elif mixed:
                         idx = rngstream.batch_block_mixed(
                             bkey, t, device_sizes, batch_size)
                         g = grads_fn(w, xs, ys, idx, batch_wts)
@@ -800,9 +813,13 @@ class FLEngine:
                         g = grads_fn(w, xs, ys, idx)
                 if payload_bf16:
                     # mixed-precision uplink: the gradient payload leaves
-                    # the device truncated to bf16; aggregation stays in
-                    # the engine's f32 accumulators
-                    g = g.astype(jnp.bfloat16).astype(jnp.float32)
+                    # the device rounded to bf16 (to nearest even);
+                    # aggregation stays in the engine's f32 accumulators.
+                    # XLA drops an astype round trip to bf16 and back; it
+                    # keeps reduce_precision
+                    with jax.named_scope("fl.uplink"):
+                        g = jax.lax.reduce_precision(g, exponent_bits=8,
+                                                     mantissa_bits=7)
                 if part is not None:
                     # Bernoulli client sampling (counter-based PARTICIPATE
                     # stream, bit-identical across backends/rng modes):
@@ -811,9 +828,12 @@ class FLEngine:
                     # upstream of the fault layer and the scheme's
                     # combiner (non-participants keep their reserved
                     # slots, like faulted devices)
-                    up = rngstream.participation_block(pkey, t, N)
-                    chi = up < part_probs
-                    g = g * (chi.astype(jnp.float32) * part_scale)[:, None]
+                    with jax.named_scope("fl.streams"):
+                        up = rngstream.participation_block(pkey, t, N)
+                    with jax.named_scope("fl.bias"):
+                        chi = up < part_probs
+                        g = g * (chi.astype(jnp.float32)
+                                 * part_scale)[:, None]
                 if amode:
                     # buffered-async delivery (counter-based ARRIVAL
                     # stream, bit-identical across backends/rng modes):
@@ -823,55 +843,62 @@ class FLEngine:
                     # zero-fill or replay their last delivered payload —
                     # applied upstream of the fault layer and the
                     # scheme's combiner, like the layers around it
-                    ua = rngstream.arrival_block(akey, t, N)
-                    g, ok_a, a_buf = async_fl.async_round(
-                        g, a_buf, ua, a_rates, a_cdf, a_disc, a_pscale)
-                    if a_stale:
-                        g, g_alast = async_fl.stale_replace(g, ok_a,
-                                                            g_alast)
-                    else:
-                        g = g * ok_a.astype(jnp.float32)[:, None]
+                    with jax.named_scope("fl.streams"):
+                        ua = rngstream.arrival_block(akey, t, N)
+                    with jax.named_scope("fl.bias"):
+                        g, ok_a, a_buf = async_fl.async_round(
+                            g, a_buf, ua, a_rates, a_cdf, a_disc, a_pscale)
+                        if a_stale:
+                            g, g_alast = async_fl.stale_replace(g, ok_a,
+                                                                g_alast)
+                        else:
+                            g = g * ok_a.astype(jnp.float32)[:, None]
                 if fault is not None:
                     # counter-based fault draws + degradation policy,
                     # applied to the payloads *upstream* of the scheme's
                     # combiner so every registered port inherits it
                     # (faulted devices keep their reserved slots; a zeroed
                     # payload quantizes to exact zeros on both backends)
-                    uf = rngstream.fault_block(fkey, t, N)
-                    okb, straggler = fault_masks(uf, jnp.abs(h), fault)
-                    if fault.on_missing == "zero":
-                        g = g * okb.astype(jnp.float32)[:, None]
-                    elif fault.on_missing == "reweight":
-                        g = g * (okb.astype(jnp.float32) / q_surv)[:, None]
-                    else:
-                        # stale: replay the last received gradient — the
-                        # single last-gradient code path shared with the
-                        # async buffer (core.async_fl)
-                        g, g_stale = async_fl.stale_replace(g, okb,
-                                                            g_stale)
-                if needs_dither:
-                    # one (N, d) block regenerated per round — the whole
-                    # dither stream never exists in memory at once
-                    u = rngstream.dither_block(dkey, t, N, d)
-                else:
-                    u = jnp.zeros((1, 1), jnp.float32)
-                ghat, lat = round_fn(g, h, z, u, selrow, t)
-                # division (not reciprocal-multiply) so OTA wall-clock is
-                # bit-equal to the trainer's ``latency_s / bandwidth`` and
-                # budget comparisons freeze on the same round
-                w_new = jnp.where(active, _project(w - eta * ghat, radius), w)
-                if fault is not None:
-                    # delivering stragglers stretch the round; a deadline
-                    # instead caps it (stragglers then miss via the mask)
+                    with jax.named_scope("fl.streams"):
+                        uf = rngstream.fault_block(fkey, t, N)
+                    with jax.named_scope("fl.bias"):
+                        okb, straggler = fault_masks(uf, jnp.abs(h), fault)
+                        if fault.on_missing == "zero":
+                            g = g * okb.astype(jnp.float32)[:, None]
+                        elif fault.on_missing == "reweight":
+                            g = g * (okb.astype(jnp.float32)
+                                     / q_surv)[:, None]
+                        else:
+                            # stale: replay the last received gradient —
+                            # the single last-gradient code path shared
+                            # with the async buffer (core.async_fl)
+                            g, g_stale = async_fl.stale_replace(g, okb,
+                                                                g_stale)
+                with jax.named_scope("fl.streams"):
+                    # one (N, d) dither block regenerated per round — the
+                    # whole stream never exists in memory at once
+                    u = (rngstream.dither_block(dkey, t, N, d)
+                         if needs_dither else jnp.zeros((1, 1), jnp.float32))
+                with jax.named_scope("fl.uplink"):
+                    ghat, lat = round_fn(g, h, z, u, selrow, t)
+                with jax.named_scope("fl.update"):
+                    w_new = jnp.where(active,
+                                      _project(w - eta * ghat, radius), w)
+                    # division (not reciprocal-multiply) so OTA wall-clock
+                    # is bit-equal to the trainer's ``latency_s /
+                    # bandwidth`` and budget comparisons freeze on the
+                    # same round
                     lat_s = lat / lat_div
-                    slow = jnp.any(straggler & okb)
-                    lat_s = jnp.where(slow, lat_s * straggler_mult, lat_s)
-                    if has_deadline:
-                        lat_s = jnp.minimum(lat_s, deadline)
+                    if fault is not None:
+                        # delivering stragglers stretch the round; a
+                        # deadline instead caps it (stragglers then miss
+                        # via the mask)
+                        slow = jnp.any(straggler & okb)
+                        lat_s = jnp.where(slow, lat_s * straggler_mult,
+                                          lat_s)
+                        if has_deadline:
+                            lat_s = jnp.minimum(lat_s, deadline)
                     t_wall = jnp.where(active, t_wall + lat_s, t_wall)
-                else:
-                    t_wall = jnp.where(active, t_wall + lat / lat_div,
-                                       t_wall)
                 out = (w_new, t_wall, active, dkey, bkey)
                 if amode:
                     out = out + (a_buf,)
@@ -888,7 +915,8 @@ class FLEngine:
                 # the eval at this segment's end is written by the trainer
                 # iff the segment's last round still ran; otherwise the slot
                 # freezes at the last written eval state
-                w_eval = jnp.where(live, w, w_eval)
+                with jax.named_scope("fl.update"):
+                    w_eval = jnp.where(live, w, w_eval)
                 return (w_eval,) + inner, (w_eval, t_wall)
 
             carry0 = (w0, w0, jnp.zeros((), jnp.float32),
@@ -910,10 +938,7 @@ class FLEngine:
                                     axis=0)
             return ws, walls
 
-        vmapped = jax.vmap(
-            trial_fn,
-            in_axes=(None, None, None, None, None, None, None,
-                     0, 0, 0, 0, 0, 0, 0, 0, None))
+        vmapped = jax.vmap(trial_fn, in_axes=_TRIAL_AXES)
         if self.shard_trials:
             n_hw = len(jax.devices())
             if trials % n_hw != 0:
@@ -924,10 +949,8 @@ class FLEngine:
             P = jax.sharding.PartitionSpec
             vmapped = jax.shard_map(
                 vmapped, mesh=mesh,
-                in_specs=(P(), P(), P(), P(), P(), P(), P(),
-                          P("trials"), P("trials"), P("trials"), P("trials"),
-                          P("trials"), P("trials"), P("trials"), P("trials"),
-                          P()),
+                in_specs=tuple(P() if a is None else P("trials")
+                               for a in _TRIAL_AXES),
                 out_specs=(P("trials"), P("trials")), check_vma=False)
         runner = jit_f32(vmapped)
         jagg._runner_cache[key] = runner
@@ -940,21 +963,48 @@ class FLEngine:
             w_star: Optional[np.ndarray] = None,
             time_budget_s: Optional[float] = None,
             rng: str = "replay") -> TrainLog:
-        jagg, runner, args = self.prepare(
-            aggregator, rounds=rounds, trials=trials, eval_every=eval_every,
-            seed=seed, time_budget_s=time_budget_s, rng=rng)
-        ws, walls = runner(*args)
-        losses, accs = self._evaluate(ws)
-        opt_err = (np.sum((np.asarray(ws, np.float64) - w_star) ** 2,
-                          axis=-1)
+        # host spans (``jax.profiler.TraceAnnotation``) on the profiler's
+        # clock, recorded only while a profiler session runs: fl.run >
+        # fl.prepare > fl.replay, fl.dispatch, fl.evaluate, fl.fetch
+        with jax.profiler.TraceAnnotation("fl.run"):
+            jagg, runner, args = self.prepare(
+                aggregator, rounds=rounds, trials=trials,
+                eval_every=eval_every, seed=seed,
+                time_budget_s=time_budget_s, rng=rng)
+            with jax.profiler.TraceAnnotation(
+                    "fl.dispatch", h2d_bytes=_host_bytes(*args)):
+                # the scan cannot start before its arguments are on the
+                # chip: waiting for them here keeps the copy in this span
+                ws, walls = runner(*self._place(args))
+            losses, accs = self._evaluate(ws)
+            with jax.profiler.TraceAnnotation("fl.fetch"):
+                losses = np.asarray(losses, np.float64)
+                accs = np.asarray(accs, np.float64)
+                walls = np.asarray(walls)
+                ws_host = (np.asarray(ws, np.float64) if w_star is not None
+                           else None)
+        opt_err = (np.sum((ws_host - w_star) ** 2, axis=-1)
                    if w_star is not None else None)
         return TrainLog(scheme=jagg.name,
                         rounds=np.arange(0, rounds + 1, eval_every,
                                          dtype=np.int64),
-                        wall_time_s=np.asarray(walls).mean(axis=0),
-                        global_loss=np.asarray(losses, np.float64),
-                        accuracy=np.asarray(accs, np.float64),
+                        wall_time_s=walls.mean(axis=0),
+                        global_loss=losses, accuracy=accs,
                         opt_error=opt_err, quantized=not jagg.is_ota)
+
+    def _place(self, args):
+        """``args`` copied to the chip(s) the scan runs on, once the copy
+        has ended: with ``shard_trials``, the trial-batched ones split over
+        the chips and the rest on every chip, as the scan takes them."""
+        if self.shard_trials:
+            mesh = jax.make_mesh((len(jax.devices()),), ("trials",))
+            P = jax.sharding.PartitionSpec
+            args = [jax.device_put(a, jax.sharding.NamedSharding(
+                        mesh, P() if ax is None else P("trials")))
+                    for a, ax in zip(args, _TRIAL_AXES)]
+        else:
+            args = jax.device_put(args)
+        return jax.block_until_ready(args)
 
     def prepare(self, aggregator, *, rounds: int, trials: int = 3,
                 eval_every: int = 10, seed: int = 0,
@@ -972,82 +1022,85 @@ class FLEngine:
             raise ValueError(
                 f"no JAX port for {type(aggregator).__name__}; "
                 "use FLTrainer.run(..., backend='numpy')")
-        eval_rounds = list(range(0, rounds + 1, eval_every))
-        n_seg = len(eval_rounds) - 1
-        T = n_seg * eval_every      # rounds past the last eval are unobserved
-        d, N = self.task.dim, self.dep.n_devices
+        with jax.profiler.TraceAnnotation("fl.prepare"):
+            eval_rounds = list(range(0, rounds + 1, eval_every))
+            n_seg = len(eval_rounds) - 1
+            T = n_seg * eval_every  # rounds past the last eval: unobserved
+            d, N = self.task.dim, self.dep.n_devices
+            f32 = np.float32
 
-        if rng == "fast":
-            # zero host-side precompute: only three (2,)-uint32 base keys
-            # per trial; fading/noise/selection regenerate in-scan
-            H = jnp.stack([rngstream.stream_base_key(
-                seed, tr, rngstream.FADING_TAG) for tr in range(trials)])
-            Z = jnp.stack([rngstream.stream_base_key(
-                seed, tr, rngstream.NOISE_TAG) for tr in range(trials)])
-            SEL = jnp.stack([rngstream.stream_base_key(
-                seed, tr, rngstream.SELECT_TAG) for tr in range(trials)])
-        else:
-            H = np.stack([sample_fading_batch(self.dep.lambdas,
-                                              seed * 1000 + tr, T)
-                          for tr in range(trials)])           # (trials, T, N)
-            if jagg.needs_noise:
-                Z = np.stack([rngstream.trial_rng(seed, tr)
-                              .standard_normal((T, d))
+            if rng == "fast":
+                # zero host-side precompute: only three (2,)-uint32 base
+                # keys per trial; fading/noise/selection regenerate in-scan
+                A = jnp.stack([rngstream.stream_base_key(
+                    seed, tr, rngstream.FADING_TAG) for tr in range(trials)])
+                B_ = jnp.stack([rngstream.stream_base_key(
+                    seed, tr, rngstream.NOISE_TAG) for tr in range(trials)])
+                C = jnp.stack([rngstream.stream_base_key(
+                    seed, tr, rngstream.SELECT_TAG) for tr in range(trials)])
+            else:
+                with jax.profiler.TraceAnnotation("fl.replay"):
+                    H = np.stack([sample_fading_batch(self.dep.lambdas,
+                                                      seed * 1000 + tr, T)
+                                  for tr in range(trials)])   # (trials, T, N)
+                    if jagg.needs_noise:
+                        Z = np.stack([rngstream.trial_rng(seed, tr)
+                                      .standard_normal((T, d))
+                                      for tr in range(trials)])
+                    else:
+                        Z = np.zeros((trials, T, 1))
+                    if jagg.sel_stream_np is not None:
+                        SEL = np.stack([jagg.sel_stream_np(seed, tr, T)
+                                        for tr in range(trials)])
+                    else:
+                        SEL = np.zeros((trials, T, 1))
+                    # the host replay stacks enter the scan rounded to f32
+                    seg = lambda a, dt: np.asarray(a.reshape(
+                        (trials, n_seg, eval_every) + a.shape[2:]), dt)
+                    A, B_, C = (seg(H, np.complex64), seg(Z, f32),
+                                seg(SEL, f32))
+            keys = jnp.stack([rngstream.dither_base_key(seed, tr)
                               for tr in range(trials)])
-            else:
-                Z = np.zeros((trials, T, 1))
-            if jagg.sel_stream_np is not None:
-                SEL = np.stack([jagg.sel_stream_np(seed, tr, T)
-                                for tr in range(trials)])     # (trials, T, S)
-            else:
-                SEL = np.zeros((trials, T, 1))
-        keys = jnp.stack([rngstream.dither_base_key(seed, tr)
-                          for tr in range(trials)])
-        bkeys = jnp.stack([rngstream.batch_base_key(seed, tr)
-                           for tr in range(trials)])
-        # fault-, participation- and arrival-stream base keys ride along
-        # unconditionally (cheap, and keeps trial_fn's arity mode-,
-        # fault-, participation- and async-blind); when the matching
-        # layer is disabled the traced program never consumes them
-        fkeys = jnp.stack([rngstream.fault_base_key(seed, tr)
-                           for tr in range(trials)])
-        pkeys = jnp.stack([rngstream.participate_base_key(seed, tr)
-                           for tr in range(trials)])
-        akeys = jnp.stack([rngstream.arrival_base_key(seed, tr)
-                           for tr in range(trials)])
+            bkeys = jnp.stack([rngstream.batch_base_key(seed, tr)
+                               for tr in range(trials)])
+            # fault-, participation- and arrival-stream base keys ride
+            # along unconditionally (cheap, and keeps trial_fn's arity
+            # mode-, fault-, participation- and async-blind); when the
+            # matching layer is disabled the traced program never
+            # consumes them
+            fkeys = jnp.stack([rngstream.fault_base_key(seed, tr)
+                               for tr in range(trials)])
+            pkeys = jnp.stack([rngstream.participate_base_key(seed, tr)
+                               for tr in range(trials)])
+            akeys = jnp.stack([rngstream.arrival_base_key(seed, tr)
+                               for tr in range(trials)])
 
-        runner = self._get_runner(jagg, trials, n_seg, eval_every, rng)
-        # host arrays, not device arrays: the jitted scan places each one
-        # where it runs (with shard_trials, straight onto every chip
-        # instead of staging a copy on the first)
-        f32 = np.float32
-        w0 = np.asarray(self.task.init_params(), f32)
-        eta = np.asarray(self.eta, f32)
-        radius = np.asarray(
-            np.inf if self.project_radius is None else self.project_radius,
-            f32)
-        lat_div = np.asarray(
-            self.dep.cfg.bandwidth_hz if jagg.is_ota else 1.0, f32)
-        budget = np.asarray(
-            np.inf if time_budget_s is None else time_budget_s, f32)
-        Ts = np.arange(T, dtype=np.int32).reshape(n_seg, eval_every)
-        if rng == "fast":
-            A, B_, C = H, Z, SEL          # per-trial base keys as-is
-        else:
-            # the host replay stacks enter the scan rounded to f32
-            seg = lambda a, dt: np.asarray(a.reshape(
-                (trials, n_seg, eval_every) + a.shape[2:]), dt)
-            A, B_, C = seg(H, np.complex64), seg(Z, f32), seg(SEL, f32)
-        args = (w0, eta, radius, lat_div, budget, self.xs, self.ys,
-                keys, bkeys, fkeys, pkeys, akeys, A, B_, C, Ts)
-        return jagg, runner, args
+            runner = self._get_runner(jagg, trials, n_seg, eval_every, rng)
+            # host arrays, not device arrays: ``run`` places each one
+            # where the scan runs (with shard_trials, straight onto every
+            # chip instead of staging a copy on the first)
+            w0 = np.asarray(self.task.init_params(), f32)
+            eta = np.asarray(self.eta, f32)
+            radius = np.asarray(np.inf if self.project_radius is None
+                                else self.project_radius, f32)
+            lat_div = np.asarray(
+                self.dep.cfg.bandwidth_hz if jagg.is_ota else 1.0, f32)
+            budget = np.asarray(
+                np.inf if time_budget_s is None else time_budget_s, f32)
+            Ts = np.arange(T, dtype=np.int32).reshape(n_seg, eval_every)
+            args = (w0, eta, radius, lat_div, budget, self.xs, self.ys,
+                    keys, bkeys, fkeys, pkeys, akeys, A, B_, C, Ts)
+            return jagg, runner, args
 
     def _evaluate(self, ws):
         """Global loss + test accuracy at every eval point, vmapped over
-        (trials * E) model states in the trainer's float32 eval precision."""
+        (trials * E) model states in the trainer's float32 eval precision:
+        launched, not fetched (two (trials, E) device arrays)."""
         trials, E, d = ws.shape
-        wf = ws.reshape(trials * E, d).astype(jnp.float32)
-        losses = self._loss_v(wf, self.x_all, self.y_all)
-        accs = self._acc_v(wf, self.x_test, self.y_test)
-        return (np.asarray(losses).reshape(trials, E),
-                np.asarray(accs).reshape(trials, E))
+        data = (self.x_all, self.y_all, self.x_test, self.y_test)
+        with jax.profiler.TraceAnnotation("fl.evaluate",
+                                          h2d_bytes=_host_bytes(*data)):
+            wf = ws.reshape(trials * E, d).astype(jnp.float32)
+            losses = self._loss_v(wf, self.x_all, self.y_all)
+            accs = self._acc_v(wf, self.x_test, self.y_test)
+            return losses.reshape(trials, E), accs.reshape(trials, E)

@@ -4,7 +4,7 @@
 this module never touches jax device state; the dry-run sets
 XLA_FLAGS=--xla_force_host_platform_device_count=512 before any jax import.
 
-Target hardware (roofline constants live in benchmarks/roofline.py):
+Target hardware:
   TPU v5e pod: 16x16 = 256 chips, (data=16, model=16)
   2 pods     : (pod=2, data=16, model=16) = 512 chips
 """

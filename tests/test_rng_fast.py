@@ -214,6 +214,28 @@ class TestPayloadDtype:
         assert not np.allclose(log32.global_loss, log16.global_loss,
                                rtol=1e-10)
 
+    def test_bf16_payload_is_the_bf16_rounding_of_f32(self, setup):
+        """The payload the combiner sees is each f32 gradient rounded to
+        the nearest bf16 (ties to even), as NumPy's bf16 rounds it: with
+        w0 = 0, eta = 1 and a combiner that passes device 0's payload on,
+        the state after one round is minus that payload."""
+        import jax.numpy as jnp
+        from repro.fl.engine import FLEngine, JaxAggregator
+
+        task, ds, dep, _ = setup
+        first = JaxAggregator(name="first", is_ota=True, needs_noise=False,
+                              round_fn=lambda g, h, z, u, sel, t: (g[0], 0.))
+        ws = {}
+        for dtype in ("f32", "bf16"):
+            eng = FLEngine(task, ds, dep, 1.0, payload_dtype=dtype)
+            _, runner, args = eng.prepare(first, rounds=1, trials=1,
+                                          eval_every=1, seed=5)
+            ws[dtype] = np.asarray(runner(*args)[0])[0, 1]
+        assert not np.any(np.asarray(task.init_params()))
+        rounded = ws["f32"].astype(jnp.bfloat16).astype(np.float32)
+        np.testing.assert_array_equal(ws["bf16"], rounded)
+        assert not np.array_equal(ws["bf16"], ws["f32"])
+
     def test_bf16_rejected_on_numpy_backend(self, setup):
         task, ds, dep, eta = setup
         tr = FLTrainer(task, ds, dep, eta=eta, payload_dtype="bf16")
