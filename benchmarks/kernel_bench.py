@@ -147,28 +147,26 @@ def _payload_case(n_dev: int, d: int, r_bits: int, seed: int = 0,
     jax.block_until_ready(words)
     del words_parts, scal_parts
     pack_s = time.perf_counter() - t0
-    block_rows = pk.block_rows
-    d_padded = words.shape[0] * (32 // cb) * 128 // n_dev
+    block_cols = pk.block_cols
+    d_padded = words.shape[1] * (32 // cb)
     wvec = jnp.full(n_dev, 1.0 / n_dev, jnp.float32)
 
     def fused_fn(wd, wv):
         return ops.packed_weighted_sum(
-            ops.PackedGrads(wd, scal, cb, n_dev, d, block_rows), wv)
+            ops.PackedGrads(wd, scal, cb, n_dev, d, block_cols), wv)
 
     fused_j = jax.jit(fused_fn)
     t_fused = _time_s(fused_j, words, wvec)
     fused_rss = _rss_mb()
 
     # materialize-then-sum baseline: same Pallas unpack technology, then a
-    # weighted matvec over the (N, d) float block. The matvec runs on the
-    # padded width and slices the (d,) result — slicing the matrix first
-    # would copy another N*d floats.
+    # weighted matvec over the (N, d) float block.
     interp = jax.default_backend() == "cpu"
 
     def base_fn(wd, wv):
-        gq = unpack_dequant_rows_2d(wd, scal, code_bits=cb, n_dev=n_dev,
-                                    interpret=interp, block_rows=block_rows)
-        return (wv @ gq.reshape(n_dev, -1))[:d]
+        gq = unpack_dequant_rows_2d(wd, scal, code_bits=cb, d=d,
+                                    block_cols=block_cols, interpret=interp)
+        return wv @ gq
 
     base_j = jax.jit(base_fn)
     t_base = _time_s(base_j, words, wvec)
@@ -186,7 +184,7 @@ def _payload_case(n_dev: int, d: int, r_bits: int, seed: int = 0,
                                 4.0 * n_dev * d_padded, t_base)
     return {
         "n_devices": n_dev, "dim": d, "dim_padded": int(d_padded),
-        "r_bits": r_bits, "code_bits": cb, "block_rows": int(block_rows),
+        "r_bits": r_bits, "code_bits": cb, "block_cols": int(block_cols),
         "packed_mb": words.nbytes / 2 ** 20,
         "materialized_mb": n_dev * d_padded * 4 / 2 ** 20,
         "pack_wall_s": pack_s,
@@ -286,7 +284,7 @@ def main() -> None:
     for c in payload["cases"]:
         f, b = c["fused"], c["baseline"]
         print(f"N={c['n_devices']} d={c['dim']} ({c['code_bits']}-bit codes, "
-              f"tile {c['block_rows']}): packed {c['packed_mb']:.0f} MB vs "
+              f"tile {c['block_cols']}): packed {c['packed_mb']:.0f} MB vs "
               f"materialized {c['materialized_mb']:.0f} MB")
         print(f"  fused    {f['wall_s']:.2f}s  RSS {f['peak_rss_mb']:.0f} MB"
               f"  ({f['achieved_bytes_per_s'] / 1e9:.2f} GB/s, "

@@ -23,7 +23,7 @@ from .linear_scan import linear_scan_fsl, CHUNK
 from .row_reduce import row_maxabs_sumsq_2d
 from .payload import (quantize_pack_rows_2d, unpack_dequant_rows_2d,
                       packed_weighted_sum_2d, CODE_BITS_CHOICES,
-                      min_block_rows)
+                      min_block_cols)
 
 # Below this payload dimension the fused pack path is not worth the extra
 # kernel: the two-step quantize + matvec fits one or two tiles anyway and
@@ -54,15 +54,35 @@ def _sublane_rows(dtype) -> int:
 BLOCK_ROWS = 512
 
 
-def _block_rows(n: int, dtype, min_rows: int = 8) -> int:
+def _block_rows(n: int, dtype) -> int:
     """Row tile for an n-element payload: the smallest power of two that
     holds all its rows, capped at BLOCK_ROWS. Never below one sublane tile
-    of ``dtype`` or ``min_rows``."""
+    of ``dtype``."""
     rows = -(-n // LANES)
     br = 8
     while br < min(rows, BLOCK_ROWS):
         br *= 2
-    return max(br, min_rows, _sublane_rows(dtype))
+    return max(br, _sublane_rows(dtype))
+
+
+#: Column block of the pack kernels (``kernels/payload.py``) on a payload
+#: of COL_BLOCK columns or more: 8 devices x 65,536 columns, 2 MiB of f32
+#: gradient a block. Every pack kernel compiles for a v5e at it, in f32
+#: and bf16 (``tests/test_chip_compile.py``); at 131,072 the pack kernel
+#: runs out of VMEM. On a v5e at N=100, d=1,663,370 with 8-bit codes the
+#: packed sum takes 2.04 ms at this block against 2.55 ms at 32,768, the
+#: pack 2.17 ms at either.
+COL_BLOCK = 65536
+
+
+def _block_cols(d: int, code_bits: int) -> int:
+    """Column block for a d-wide payload: the smallest power of two that
+    holds all its columns, capped at COL_BLOCK, never below one 128-lane
+    tile per packed chunk."""
+    bc = min_block_cols(code_bits)
+    while bc < min(d, COL_BLOCK):
+        bc *= 2
+    return bc
 
 
 def _to_blocks(x: jnp.ndarray, block_rows: int):
@@ -161,15 +181,16 @@ class PackedGrads:
 
     words holds each device's quantizer codes at ``code_bits`` per entry,
     K = 32/code_bits codes per uint32 — code_bits/32 the bytes of the
-    float block it replaces. scal: (N, 2) f32 per-device (||g||_inf,
-    levels).
+    float block it replaces; row i is device i's payload, packed in
+    column blocks of ``block_cols`` entries. scal: (N, 2) f32 per-device
+    (||g||_inf, levels).
     """
-    words: jnp.ndarray        # (N * R_dev / K, LANES) uint32
+    words: jnp.ndarray        # (N, cdiv(d, block_cols) * block_cols / K)
     scal: jnp.ndarray         # (N, 2)
     code_bits: int
     n_dev: int
     d: int
-    block_rows: int
+    block_cols: int
 
 
 def quantize_pack(gs: jnp.ndarray, levels: jnp.ndarray, dither: jnp.ndarray,
@@ -177,22 +198,23 @@ def quantize_pack(gs: jnp.ndarray, levels: jnp.ndarray, dither: jnp.ndarray,
     """Fused dither -> quantize -> bit-pack of N device gradients.
 
     gs/dither: (N, d); levels: (N,) per-device 2^{r_m} - 1 with
-    r_m <= code_bits. One Pallas pass per device block; the dequantized
-    float tensor is never formed.
+    r_m <= code_bits. One Pallas pass reads gs and dither where they lie;
+    the dequantized float tensor is never formed.
     """
     n_dev, d = gs.shape
     m = jnp.max(jnp.abs(gs), axis=1).astype(jnp.float32)
+    # one array for the scale and the kernel: left free, XLA recomputed gs
+    # for the kernel and tiled the scale's operand (1, 128), one sublane of
+    # eight, which made the payload cell's gradients 9x slower on a v5e
+    # (5.5 -> 47.9 ms a round)
+    gs, m = jax.lax.optimization_barrier((gs, m))
     levels = jnp.asarray(levels, jnp.float32)
-    dither = dither.astype(jnp.float32)
-    br = _block_rows(d, gs.dtype, min_rows=min_block_rows(code_bits))
-    per = br * LANES
-    d_pad = (-d) % per
-    pad = lambda x: jnp.pad(x, ((0, 0), (0, d_pad))).reshape(-1, LANES)
+    bc = _block_cols(d, code_bits)
     scal = jnp.stack([m, levels], axis=1)
-    words = quantize_pack_rows_2d(pad(gs), pad(dither), scal,
-                                  code_bits=code_bits,
-                                  interpret=_on_cpu(), block_rows=br)
-    return PackedGrads(words, scal, code_bits, n_dev, d, br)
+    words = quantize_pack_rows_2d(gs, dither.astype(jnp.float32), scal,
+                                  code_bits=code_bits, block_cols=bc,
+                                  interpret=_on_cpu())
+    return PackedGrads(words, scal, code_bits, n_dev, d, bc)
 
 
 def unpack_dequant(pk: PackedGrads) -> jnp.ndarray:
@@ -202,24 +224,9 @@ def unpack_dequant(pk: PackedGrads) -> jnp.ndarray:
     ``dithered_quantize_batch`` output, and the O(N*d) baseline the fused
     ``packed_weighted_sum`` is benchmarked against.
     """
-    out = unpack_dequant_rows_2d(pk.words, pk.scal, code_bits=pk.code_bits,
-                                 n_dev=pk.n_dev, interpret=_on_cpu(),
-                                 block_rows=pk.block_rows)
-    return out.reshape(pk.n_dev, -1)[:, :pk.d]
-
-
-def _dev_block(n_dev: int) -> int:
-    """Devices per grid step for the fused accumulate. On CPU/interpret
-    the per-grid-step overhead dominates (every step copies the operand
-    buffers), so group as many whole payloads per step as divide N; on
-    TPU a multi-payload block would blow VMEM, so keep the tiled launch
-    (dev_block=1, the launch ``tests/test_chip_compile.py`` compiles)."""
-    if not _on_cpu():
-        return 1
-    for db in (16, 8, 4, 2):
-        if n_dev % db == 0:
-            return db
-    return 1
+    return unpack_dequant_rows_2d(pk.words, pk.scal, code_bits=pk.code_bits,
+                                  d=pk.d, block_cols=pk.block_cols,
+                                  interpret=_on_cpu())
 
 
 def packed_weighted_sum(pk: PackedGrads, weights: jnp.ndarray) -> jnp.ndarray:
@@ -234,10 +241,9 @@ def packed_weighted_sum(pk: PackedGrads, weights: jnp.ndarray) -> jnp.ndarray:
     w = jnp.asarray(weights, jnp.float32).reshape(-1, 1)
     scal3 = jnp.concatenate([pk.scal, w], axis=1)
     out = packed_weighted_sum_2d(pk.words, scal3, code_bits=pk.code_bits,
-                                 n_dev=pk.n_dev, interpret=_on_cpu(),
-                                 block_rows=pk.block_rows,
-                                 dev_block=_dev_block(pk.n_dev))
-    return out.reshape(-1)[:pk.d]
+                                 d=pk.d, block_cols=pk.block_cols,
+                                 interpret=_on_cpu())
+    return out.reshape(-1)
 
 
 def quantized_weighted_sum(gs: jnp.ndarray, levels: jnp.ndarray,

@@ -279,18 +279,19 @@ def test_quantize_pack_roundtrip_exact_dtype(dtype):
         np.asarray(ops.dithered_quantize_batch(gs, levels, us)))
 
 
-@pytest.mark.parametrize("tile", [ops.BLOCK_ROWS, 2048])
-@pytest.mark.parametrize("n_dev,d", [(4, 1000), (8, 200_000), (5, 131_073)])
+@pytest.mark.parametrize("tile", [ops.COL_BLOCK, 4096])
+@pytest.mark.parametrize("n_dev,d", [(4, 1000), (8, 200_000), (5, 131_073),
+                                     (100, 131_073)])
 def test_quantized_weighted_sum_fused_matches_two_step(n_dev, d, tile,
                                                        monkeypatch):
     """Fused kernel == sequential jnp reference == two-step quantize +
     matvec, to accumulation-order tolerance (FMA contraction / summation
     association differ; the payload decode itself is bit-exact). Covers
-    the device-blocked launch (n_dev divisible by the group) and the
-    tiled fallback (n_dev=5). The 512-row tile puts several packed blocks
-    in each device's payload; the 2048-row one, the largest every kernel
-    compiles at on a v5e, one or two."""
-    monkeypatch.setattr(ops, "BLOCK_ROWS", tile)
+    whole device blocks (n_dev=8), a single partial one (4, 5) and the
+    payload cell's N=100 (12 whole blocks and a partial one). The
+    65,536-column tile puts two to four column blocks in each device's
+    payload at d >= 10^5; the 4096-column one, dozens."""
+    monkeypatch.setattr(ops, "COL_BLOCK", tile)
     rng = np.random.default_rng(n_dev)
     gs = jnp.asarray(rng.normal(size=(n_dev, d)), jnp.float32)
     us = jnp.asarray(rng.uniform(size=(n_dev, d)), jnp.float32)
@@ -305,6 +306,47 @@ def test_quantized_weighted_sum_fused_matches_two_step(n_dev, d, tile,
     np.testing.assert_allclose(np.asarray(fused_k), np.asarray(fused_r),
                                rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(np.asarray(fused_k), np.asarray(two_step),
+                               rtol=1e-5, atol=1e-6)
+
+
+def _codes(pk):
+    """The (N, W*K) codes packed in ``pk.words``, unpacked with NumPy in
+    the kernels' layout: chunk k of each column block's block_cols/K
+    columns in bits [k*code_bits, (k+1)*code_bits) of its words."""
+    K = 32 // pk.code_bits
+    bcw = pk.block_cols // K
+    words = np.asarray(pk.words).reshape(pk.n_dev, -1, 1, bcw)
+    shifts = (np.arange(K, dtype=np.uint32) * pk.code_bits).reshape(K, 1)
+    codes = (words >> shifts) & np.uint32((1 << pk.code_bits) - 1)
+    return codes.reshape(pk.n_dev, -1)
+
+
+@pytest.mark.parametrize("n_dev,d", [(13, 5000), (13, 70_001),
+                                     (8, 32_768 + 128)])
+@pytest.mark.parametrize("code_bits", [4, 8, 16])
+def test_pack_kernels_partial_blocks(code_bits, n_dev, d):
+    """A partial last block of devices (N=13: one whole block of 8 and 5
+    rows) and of columns (d not a multiple of the column block) in both
+    kernels: the decode equals the two-step quantizer bit for bit, every
+    code past column d is 0 (no stray bits next to a real code), and the
+    fused sum agrees with the sequential reference."""
+    rng = np.random.default_rng(code_bits * n_dev + d)
+    gs = jnp.asarray(rng.normal(size=(n_dev, d)), jnp.float32)
+    us = jnp.asarray(rng.uniform(size=(n_dev, d)), jnp.float32)
+    top = 2 ** code_bits - 1
+    levels = jnp.asarray([float(min(top, 2 ** (1 + i) - 1))
+                          for i in range(n_dev)], jnp.float32).at[2].set(0.0)
+    w = jnp.asarray(rng.uniform(0.1, 1.0, size=n_dev), jnp.float32)
+    pk = ops.quantize_pack(gs, levels, us, code_bits=code_bits)
+    assert d % pk.block_cols
+    np.testing.assert_array_equal(
+        np.asarray(ops.unpack_dequant(pk)),
+        np.asarray(ops.dithered_quantize_batch(gs, levels, us)))
+    assert not _codes(pk)[:, d:].any()
+    fused_k = ops.packed_weighted_sum(pk, w)
+    fused_r = ops.quantized_weighted_sum(gs, levels, us, w, r_max=code_bits,
+                                         fused=True, use_kernel=False)
+    np.testing.assert_allclose(np.asarray(fused_k), np.asarray(fused_r),
                                rtol=1e-5, atol=1e-6)
 
 
@@ -360,15 +402,29 @@ def test_row_maxabs_sumsq_bf16_payload_f32_accumulate():
     np.testing.assert_allclose(np.asarray(s16), np.asarray(s32), rtol=1e-2)
 
 
-@pytest.mark.parametrize("n,dtype,min_rows,want", [
-    (100 * 128, "float32", 8, 128),        # below the cap: pow2 clamp
-    (1000 * 128, "float32", 8, 512),       # payload width: the constant
-    (10 ** 6, "bfloat16", 8, 512),
-    (3 * 128, "float32", 8, 8),            # one sublane tile of f32
-    (3 * 128, "bfloat16", 8, 16),          # one sublane tile of bf16
-    (3 * 128, "float32", 64, 64),          # a packed kernel's whole chunk
+@pytest.mark.parametrize("n,dtype,want", [
+    (100 * 128, "float32", 128),           # below the cap: pow2 clamp
+    (1000 * 128, "float32", 512),          # payload width: the constant
+    (10 ** 6, "bfloat16", 512),
+    (3 * 128, "float32", 8),               # one sublane tile of f32
+    (3 * 128, "bfloat16", 16),             # one sublane tile of bf16
 ])
-def test_block_rows(n, dtype, min_rows, want):
+def test_block_rows(n, dtype, want):
     """The row tile: the smallest power of two that holds the payload's
-    rows, capped at BLOCK_ROWS, never below a sublane tile or min_rows."""
-    assert ops._block_rows(n, dtype, min_rows=min_rows) == want
+    rows, capped at BLOCK_ROWS, never below a sublane tile."""
+    assert ops._block_rows(n, dtype) == want
+
+
+@pytest.mark.parametrize("d,code_bits,want", [
+    (1_663_370, 8, 65_536),                # payload width: the constant
+    (20_000, 8, 32_768),                   # below the cap: pow2 clamp
+    (5000, 16, 8192),
+    (100, 16, 256),                        # one 128-lane tile a chunk
+    (100, 8, 512),
+    (100, 4, 1024),
+])
+def test_block_cols(d, code_bits, want):
+    """The pack kernels' column block: the smallest power of two that
+    holds the payload's columns, capped at COL_BLOCK, never below one
+    128-lane tile per packed chunk."""
+    assert ops._block_cols(d, code_bits) == want
