@@ -31,7 +31,6 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 def readings_for(reg, workload: str, seeds: int, control_seeds: int,
                  base_seed: int, faults=(), fault_seeds: int = 3) -> dict:
-    import numpy as np
     from repro import compile_cache
 
     from bench import faults as F, fl
@@ -59,8 +58,7 @@ def readings_for(reg, workload: str, seeds: int, control_seeds: int,
         out = []
         for i in range(n):
             seed = fl.call_seed(base_seed, i)
-            got = cell.run(seed)
-            got["ws"] = np.asarray(got["ws"])
+            got = fl.to_host(cell.run(seed))
             out.append(dict(fl.readings(got, want(seed)), seed=seed))
         return out
 

@@ -7,6 +7,11 @@ hands to the engine's evaluation, are kept for a sample of the calls drawn
 from the run's seed (reservoir sampling, so the sample is uniform over
 however many calls the window holds); after the window the plain
 reference recomputes those calls and :func:`readings` compares them.
+
+Kept states leave the chip: a kept call's states start their copy to the
+host when the call returns and are held as a host float32 array from the
+next call's return on, so the chip holds the states of the current call
+and at most those of the one before it, at any model width.
 """
 from __future__ import annotations
 
@@ -30,6 +35,7 @@ class Cell:
         self.base_seed = base_seed
         self.keep = keep
         self.kept: list = []          # [(index, seed, outputs)]
+        self._pending = None          # the last kept call's outputs
         self._pick = np.random.default_rng((int(base_seed), 7))
         self._ws = []
         evaluate = engine._evaluate
@@ -67,15 +73,76 @@ class Cell:
                 "acc": log.accuracy, "wall": log.wall_time_s}
 
     def call(self, index: int) -> None:
-        """One timed call; its outputs join the sample or are dropped."""
+        """One timed call; its outputs join the sample or are dropped.
+
+        Whether they join is drawn before the call, with the same draws in
+        the same order as a draw after it, so a run seed samples the same
+        calls. A kept call's states start their copy to the host as it
+        returns; the kept call before it is settled on the host first."""
+        slot = len(self.kept)
+        if slot >= self.keep:
+            j = int(self._pick.integers(0, index + 1))
+            slot = j if j < self.keep else None
         seed = call_seed(self.base_seed, index)
         out = self.run(seed)
-        if len(self.kept) < self.keep:
-            self.kept.append((index, seed, out))
+        self.settle()
+        if slot is None:
+            return                    # its states go with ``out``
+        if hasattr(out["ws"], "copy_to_host_async"):   # on a device
+            out["ws"].copy_to_host_async()
+        entry = (index, seed, out)
+        if slot < len(self.kept):
+            self.kept[slot] = entry
         else:
-            j = int(self._pick.integers(0, index + 1))
-            if j < self.keep:
-                self.kept[j] = (index, seed, out)
+            self.kept.append(entry)
+        self._pending = out
+
+    def settle(self) -> None:
+        """Hold the last kept call's states as a host array and let their
+        device buffer go (the window's end calls this too)."""
+        if self._pending is not None:
+            to_host(self._pending)
+            self._pending = None
+
+
+def to_host(out: dict) -> dict:
+    """``out["ws"]`` as a host float32 array, in place: the device array
+    is dropped, so its buffer is freed once nothing else holds it."""
+    ws = out["ws"]
+    host = np.asarray(ws, np.float32)
+    devices = getattr(ws, "devices", None)
+    if devices is not None and {d.platform for d in devices()} == {"cpu"}:
+        host = host.copy()            # a CPU array's view holds its buffer
+    out["ws"] = host
+    return out
+
+
+#: Elements of each float32 operand converted to float64 at a time.
+CHUNK = 1 << 22
+
+
+def _square_sums(ws: np.ndarray, wr: np.ndarray) -> np.ndarray:
+    """Float64 sums of squares per trial and eval point after the first:
+    ``[||w - w_ref||^2, ||w_ref||^2, ||w_ref - w_ref[:, 0]||^2]``, over
+    chunks of about :data:`CHUNK` elements, with no whole-array float64
+    copy."""
+    trials, E, d = wr.shape
+    out = np.zeros((3, trials, E - 1))
+    cols = min(d, CHUNK)
+    rows = max(1, CHUNK // cols)
+    for t in range(trials):
+        for c in range(0, d, cols):
+            r0 = wr[t, 0, c:c + cols].astype(np.float64)
+            for e in range(1, E, rows):
+                got = ws[t, e:e + rows, c:c + cols].astype(np.float64)
+                ref = wr[t, e:e + rows, c:c + cols].astype(np.float64)
+                at = slice(e - 1, e - 1 + len(ref))
+                got -= ref
+                out[0, t, at] += np.sum(got * got, axis=-1)
+                out[1, t, at] += np.sum(ref * ref, axis=-1)
+                ref -= r0
+                out[2, t, at] += np.sum(ref * ref, axis=-1)
+    return out
 
 
 def readings(got: dict, want: dict) -> dict:
@@ -83,23 +150,27 @@ def readings(got: dict, want: dict) -> dict:
 
     * ``state_gap``: the largest ||w - w_ref|| / ||w_ref|| over trials and
       eval points after the first (both start from the same w0);
+    * ``update_gap``: the largest ||w - w_ref|| / ||w_ref - w_ref[:, 0]||
+      over the same points: the gap against the reference's update, which
+      w0 does not swamp where it is large;
     * ``loss_gap``: the largest |F - F_ref| / |F_ref| of the global loss;
     * ``acc_gap``: the largest |acc - acc_ref| of the test accuracy;
     * ``wall_gap``: the largest relative gap of the simulated wall-clock
       at the eval points where it is not zero.
+
+    The states' norms are float64 sums over chunks of the float32 arrays.
     """
-    ws, wr = np.asarray(got["ws"], np.float64), np.asarray(want["ws"],
-                                                           np.float64)
+    ws, wr = np.asarray(got["ws"]), np.asarray(want["ws"])
     if ws.shape != wr.shape:
-        return {"state_gap": np.inf, "loss_gap": np.inf, "acc_gap": np.inf,
-                "wall_gap": np.inf}
-    num = np.linalg.norm(ws[:, 1:] - wr[:, 1:], axis=-1)
-    den = np.maximum(np.linalg.norm(wr[:, 1:], axis=-1), 1e-30)
+        return {"state_gap": np.inf, "update_gap": np.inf,
+                "loss_gap": np.inf, "acc_gap": np.inf, "wall_gap": np.inf}
+    num, ref, upd = np.sqrt(_square_sums(ws, wr))
     lp, lr = np.asarray(got["loss"]), np.asarray(want["loss"])
     wp, wr_ = np.asarray(got["wall"]), np.asarray(want["wall"])
     live = np.abs(wr_) > 0
     return {
-        "state_gap": float(np.max(num / den)),
+        "state_gap": float(np.max(num / np.maximum(ref, 1e-30))),
+        "update_gap": float(np.max(num / np.maximum(upd, 1e-30))),
         "loss_gap": float(np.max(np.abs(lp - lr) / np.abs(lr))),
         "acc_gap": float(np.max(np.abs(np.asarray(got["acc"])
                                        - np.asarray(want["acc"])))),

@@ -1,11 +1,12 @@
 """``packed_weighted_sum_2d``: unpack -> dequantize -> weighted sum.
 
-Operands: the (N, 1, 3) per-device scalars and the (N R / K, 128) packed
-words; result: the (R, 128) f32 accumulator. Per code (N x the result's size)
-the kernel shifts and masks it out of its word (2), converts it (1),
-dequantizes ``-m + step q`` (2) and accumulates ``acc + w x`` (2): 7
-operations. The words are read once; the accumulator block stays resident
-across the device axis and is written once.
+Operands: the (N, 3) per-device scalars (m_i, levels_i, w_i) and the
+(N, W) packed words, W = d / K padded to whole column blocks, K = 32 /
+code_bits codes a word; result: the (1, d) f32 sum. Per code (N x the
+result's size) the kernel shifts and masks it out of its word (2),
+converts it (1), dequantizes ``-m + step q`` (2) and accumulates
+``acc + w x`` (2): 7 operations. The words are read once; each (1, block)
+accumulator stays resident across the device axis and is written once.
 """
 from bench.trace import hbm_bytes
 

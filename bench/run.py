@@ -91,7 +91,6 @@ def per_layer(reg, cell_entry, ctx) -> dict:
 
 def run(args, reg) -> dict:
     import jax
-    import numpy as np
     from repro import compile_cache
 
     from bench import fl, trace as tr, window
@@ -144,6 +143,7 @@ def run(args, reg) -> dict:
     in_window = compiles.programs - before
     if args.trace:
         jax.profiler.stop_trace()
+    cell.settle()                    # the last kept call's states, on the host
     print(f"window: calls={len(calls)} seconds="
           f"{window.window_seconds(calls):.6f} programs_compiled_or_loaded="
           f"{in_window}", file=sys.stderr, flush=True)
@@ -190,7 +190,6 @@ def run(args, reg) -> dict:
     reference = ref_mod.Reference(config, traffic)
     worst: dict = {}
     for index, seed, got in kept:
-        got = dict(got, ws=np.asarray(got["ws"]))
         r = fl.readings(got, reference.run(seed))
         bad = not passed(check(r, limits))
         result["failed"] += int(bad)

@@ -29,34 +29,12 @@ from bench import faults, fl, streams, trace as tr, window  # noqa: E402
 from bench.registry import Registry  # noqa: E402
 
 TESTDATA = ROOT / "bench" / "testdata"
-#: The payload OTA cell, built and read on the chip but not yet measured in
-#: full sets, so not in BENCHMARK.json; the tests run it all the same.
-PAYLOAD_ENTRIES = Path(__file__).with_name("payload_entries.json")
-
-
-def _with_payload(root: Path) -> None:
-    """Add the unlisted payload cell to the ``BENCHMARK.json`` at ``root``."""
-    spec = json.loads((root / "BENCHMARK.json").read_text())
-    for key, entries in json.loads(PAYLOAD_ENTRIES.read_text()).items():
-        names = {e["name"] for e in spec[key]}
-        spec[key] += [e for e in entries if e["name"] not in names]
-    (root / "BENCHMARK.json").write_text(json.dumps(spec))
-
-
-@pytest.fixture(scope="module")
-def full_reg(tmp_path_factory):
-    """The benchmark's files with every payload cell listed."""
-    root = tmp_path_factory.mktemp("spec")
-    shutil.copy(ROOT / "BENCHMARK.json", root)
-    _with_payload(root)
-    (root / "bench").symlink_to(ROOT / "bench")
-    return Registry(root)
 
 
 # ------------------------------------------------------------ registry
 
-def test_every_cell_resolves_by_name(full_reg):
-    reg = full_reg
+def test_every_cell_resolves_by_name():
+    reg = Registry()
     for cell in reg.spec["workloads"]:
         config = reg.config(cell["config"])
         assert config["name"] == cell["config"]
@@ -160,6 +138,144 @@ def test_call_seeds_are_fixed_by_the_run_seed():
     assert fl.call_seed(big + 1, 0) != fl.call_seed(big, 0)
 
 
+# ------------------------------------ the window's sample and its check
+
+class _FakeEngine:
+    """Stands in for ``FLEngine``: each call's states are a device array of
+    a shape no other test makes, and a weak reference to each is kept."""
+
+    SHAPE = (2, 3, 17)
+
+    def __init__(self, base_seed):
+        import jax
+        self.jax = jax
+        self.dep = argparse.Namespace(n_devices=4)
+        self.index = {fl.call_seed(base_seed, i): i for i in range(-1, 64)}
+        self.states: list = []        # weakref of call i's states
+        self.inside: list = []        # live earlier states during call i
+
+    def _evaluate(self, ws):
+        return None
+
+    def _alive(self, before: int) -> list:
+        return [j for j, r in enumerate(self.states[:before])
+                if r() is not None]
+
+    def run(self, aggregator, *, seed, **kw):
+        import weakref
+        i = self.index[seed]
+        ws = self.jax.numpy.full(self.SHAPE, float(i), np.float32)
+        self.states.append(weakref.ref(ws))
+        self._evaluate(ws)
+        self.inside.append(self._alive(max(i - 1, 0)))
+        z = np.zeros((2, 3))
+        return argparse.Namespace(global_loss=z, accuracy=z,
+                                  wall_time_s=z[0])
+
+
+def _reservoir_after_the_call(base_seed: int, keep: int, calls: int) -> list:
+    """The calls sampled when the draw is taken after each call returns."""
+    pick = np.random.default_rng((int(base_seed), 7))
+    kept: list = []
+    for index in range(calls):
+        if len(kept) < keep:
+            kept.append(index)
+        else:
+            j = int(pick.integers(0, index + 1))
+            if j < keep:
+                kept[j] = index
+    return kept
+
+
+@pytest.mark.parametrize("base_seed", (3, 2 ** 31 + 11, 4_000_000_007))
+def test_kept_states_leave_the_chip(base_seed):
+    """No call's states outlive the next call on the device; the kept ones
+    are host arrays; the sample is the one a draw after the call took."""
+    import jax
+    eng = _FakeEngine(base_seed)
+    cell = fl.Cell(eng, None, {"trials": 2, "rounds": 2, "eval_every": 1},
+                   base_seed, keep=3)
+    def live_calls():                         # the call a state is of
+        return {int(a.ravel()[0]) for a in jax.live_arrays()
+                if a.shape == eng.SHAPE}
+
+    for i in range(10):
+        cell.call(i)
+        assert eng._alive(i) == [], i         # all but this call's: gone
+        assert live_calls() <= {i}
+        assert eng.inside[i] == [], i         # none before the previous
+    cell.settle()
+    assert eng._alive(10) == []
+    assert live_calls() == set()
+    assert [k[0] for k in cell.kept] == \
+        _reservoir_after_the_call(base_seed, 3, 10)
+    for index, seed, out in cell.kept:
+        assert type(out["ws"]) is np.ndarray
+        assert out["ws"].dtype == np.float32
+        assert (out["ws"] == index).all()
+        assert seed == fl.call_seed(base_seed, index)
+
+
+def _readings_whole(got: dict, want: dict) -> dict:
+    """The check's numbers from whole-array float64 copies."""
+    ws = np.asarray(got["ws"], np.float64)
+    wr = np.asarray(want["ws"], np.float64)
+    num = np.linalg.norm(ws[:, 1:] - wr[:, 1:], axis=-1)
+    den = np.maximum(np.linalg.norm(wr[:, 1:], axis=-1), 1e-30)
+    upd = np.maximum(np.linalg.norm(wr[:, 1:] - wr[:, :1], axis=-1), 1e-30)
+    lp, lr = np.asarray(got["loss"]), np.asarray(want["loss"])
+    wp, wr_ = np.asarray(got["wall"]), np.asarray(want["wall"])
+    live = np.abs(wr_) > 0
+    return {
+        "state_gap": float(np.max(num / den)),
+        "update_gap": float(np.max(num / upd)),
+        "loss_gap": float(np.max(np.abs(lp - lr) / np.abs(lr))),
+        "acc_gap": float(np.max(np.abs(np.asarray(got["acc"])
+                                       - np.asarray(want["acc"])))),
+        "wall_gap": float(np.max(np.abs(wp[live] - wr_[live])
+                                 / np.abs(wr_[live])) if live.any() else 0.0),
+    }
+
+
+@pytest.mark.parametrize("chunk", (64, 1000, 3000, 1 << 22))
+def test_chunked_readings_equal_whole_array_formulas(monkeypatch, chunk):
+    """Chunks of columns (64), one row (1000) or several rows (3000) at a
+    time give the whole-array float64 numbers."""
+    monkeypatch.setattr(fl, "CHUNK", chunk)
+    rng = np.random.default_rng(chunk)
+    trials, E, d = 3, 7, 1000
+    wr = rng.standard_normal((trials, E, d)).astype(np.float32)
+    ws = (wr + 1e-3 * rng.standard_normal(wr.shape)).astype(np.float32)
+    loss = rng.random((trials, E)) + 0.5
+    wall = np.concatenate([[0.0], rng.random(E - 1) + 0.1])
+    got = {"ws": ws, "loss": loss * (1 + 1e-4 * rng.random((trials, E))),
+           "acc": rng.random((trials, E)), "wall": wall * 1.001}
+    want = {"ws": wr, "loss": loss, "acc": rng.random((trials, E)),
+            "wall": wall}
+    r, whole = fl.readings(got, want), _readings_whole(got, want)
+    assert set(r) == set(whole)
+    for k, v in whole.items():
+        assert v > 0
+        assert r[k] == pytest.approx(v, rel=1e-12, abs=0), k
+
+
+def test_update_gap_against_a_hand_count():
+    # the reference moves w0 = (1, 0) by (3, 4), then by (6, 8) in all
+    want = {"ws": np.array([[[1, 0], [4, 4], [7, 8]]], np.float32),
+            "loss": np.ones((1, 3)), "acc": np.zeros((1, 3)),
+            "wall": np.zeros(3)}
+    # the program is 0.5 off at both eval points; its w0 does not count
+    got = dict(want, ws=np.array([[[9, 9], [4.5, 4], [7, 8.5]]],
+                                 np.float32))
+    r = fl.readings(got, want)
+    assert r["update_gap"] == pytest.approx(max(0.5 / 5, 0.5 / 10),
+                                            rel=1e-12)
+    assert r["state_gap"] == pytest.approx(0.5 / np.sqrt(32), rel=1e-12)
+    assert r["loss_gap"] == r["acc_gap"] == r["wall_gap"] == 0.0
+    shorter = dict(got, ws=got["ws"][:, :2])
+    assert fl.readings(shorter, want)["update_gap"] == np.inf
+
+
 # -------------------------------------------------------- FLOP counts
 
 def test_softmax_gradient_flops_against_a_hand_count():
@@ -184,17 +300,17 @@ def test_softmax_gradient_flops_against_a_hand_count():
     del jax
 
 
-def test_payload_gradient_flops(full_reg):
-    reg = full_reg
+def test_payload_gradient_flops():
+    reg = Registry()
     b = reg.module("configs", "fedavg_cnn_uplink")
     assert b.grad_flops_per_round(reg.config("fedavg_cnn_uplink"), {}) == \
         4 * 1_663_370 * 100
 
 
-def test_payload_width_is_the_sourced_cnn(full_reg):
+def test_payload_width_is_the_sourced_cnn():
     """d is the parameter count of FedAvg's MNIST CNN, worked out from its
     layers; the cohort is Table 1's K = 100 clients at C = 1.0."""
-    cfg = full_reg.config("fedavg_cnn_uplink")
+    cfg = Registry().config("fedavg_cnn_uplink")
     conv1 = 5 * 5 * 1 * 32 + 32
     conv2 = 5 * 5 * 32 * 64 + 64
     fc = 7 * 7 * 64 * 512 + 512
@@ -227,24 +343,22 @@ def test_ota_combine_count():
 
 def test_payload_kernel_counts():
     reg = Registry()
-    # N=100, d=1,663,370 padded to 26 blocks of 512 x 128 (13,312 rows of
-    # 128) a device, 8-bit codes
-    per_dev = 13_312
-    assert per_dev * 128 == -(-1_663_370 // (512 * 128)) * 512 * 128
-    rows = 100 * per_dev
+    # N=100, d=1,663,370 read in place; 8-bit codes, 4 a word, in 26
+    # column blocks of 65,536: 425,984 words a device
+    n, d, words = 100, 1_663_370, 26 * 65_536 // 4
     pack = reg.module("kernels", "quantize_pack_rows_2d")
-    ops = [_a("f32", (100, 1, 2), True), _a("f32", (rows, 128)),
-           _a("f32", (rows, 128))]
-    res = [_a("u32", (rows // 4, 128))]
+    ops = [_a("f32", (n, 2), True), _a("f32", (n, d)), _a("f32", (n, d))]
+    res = [_a("u32", (n, words))]
     flops, nbytes = pack.cost(ops, res)
-    assert flops == 10 * rows * 128
-    assert nbytes == 2 * rows * 128 * 4 + rows // 4 * 128 * 4
+    assert flops == 10 * n * d
+    assert nbytes == 2 * n * d * 4 + n * words * 4
+    # the sum reads the words once; its (1, d) result stays in VMEM
     wsum = reg.module("kernels", "packed_weighted_sum_2d")
-    ops = [_a("f32", (100, 1, 3), True), _a("u32", (rows // 4, 128))]
-    res = [_a("f32", (per_dev, 128), True)]
+    ops = [_a("f32", (n, 3), True), _a("u32", (n, words))]
+    res = [_a("f32", (1, d), True)]
     flops, nbytes = wsum.cost(ops, res)
-    assert flops == 7 * 100 * per_dev * 128
-    assert nbytes == rows // 4 * 128 * 4
+    assert flops == 7 * n * d
+    assert nbytes == n * words * 4
 
 
 def test_kernel_event_parsing():
@@ -364,7 +478,6 @@ def _tiny_tree(root: Path, sizes: dict) -> Registry:
     """A copy of the benchmark whose cells run at CPU sizes; the
     configuration modules, references and limits are the real ones."""
     shutil.copy(ROOT / "BENCHMARK.json", root)
-    _with_payload(root)
     shutil.copytree(ROOT / "bench", root / "bench",
                     ignore=shutil.ignore_patterns("testdata"))
     b = root / "bench"
